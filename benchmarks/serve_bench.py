@@ -1169,6 +1169,12 @@ def main():
         help="skip the multi-process (worker) fleet scaling sweep",
     )
     ap.add_argument(
+        "--proc-fleet-sweep",
+        action="store_true",
+        help="run the proc-fleet sweep on a TPU backend too, where it is off by "
+        "default: one process holds a chip, so build_server refuses workers there",
+    )
+    ap.add_argument(
         "--proc-fleet-workers",
         default="1,2,4",
         help="comma-separated worker-process counts for the proc-fleet sweep",
@@ -1197,6 +1203,9 @@ def main():
     args = ap.parse_args()
 
     from repro.core.cost_model import make_cost_provider
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     provider = make_cost_provider(args.cost, cache_path=args.cost_cache)
 
@@ -1399,7 +1408,11 @@ def main():
         )
 
     proc_fleet = None
-    if not args.skip_proc_fleet_sweep:
+    import jax
+
+    if not args.skip_proc_fleet_sweep and (
+        args.proc_fleet_sweep or jax.default_backend() != "tpu"
+    ):
         proc_fleet = run_proc_fleet_sweep(
             img, args.base, args.norm, args.microbatch,
             worker_counts=tuple(int(x) for x in args.proc_fleet_workers.split(",")),
@@ -1470,7 +1483,6 @@ def main():
         "replan_scenario": replan_scenario,
         "results": results,
     }
-    import jax
 
     # runner identity for the per-machine trend store: BENCH_MACHINE lets
     # CI pin a stable key (ephemeral runners get a fresh hostname per job,

@@ -139,8 +139,9 @@ class DevicePool:
     1-device hosts — CPU CI — every replica binds the virtual 2-engine
     GPU/DLA pair to the single device, so the whole fleet still runs.
     Placement is exposed as per-engine ``place_fns`` (``jax.device_put``
-    closures) in the shape ``StreamExecutor`` consumes; on a 1-device
-    pool they collapse to identity so the hot path pays nothing.
+    closures) in the shape ``StreamExecutor`` consumes, and the weights as
+    per-engine copies made once at build time (``place_params``); on a
+    1-device pool both collapse to identity so the hot path pays nothing.
     """
 
     def __init__(self, engines, devices=None):
@@ -205,3 +206,20 @@ class DevicePool:
                 lambda state, dev=dev: jax.tree.map(lambda x: jax.device_put(x, dev), state)
             )
         return fns
+
+    def place_params(self, models, replica: int, n_replicas: int) -> list | None:
+        """Every model's params, copied once onto each engine's device of
+        this replica: ``out[engine][model]``. Engines that share a device
+        share the copy. None on a 1-device pool, where the models' own
+        params already live on the one device."""
+        if len(self.devices) == 1:
+            return None
+        import jax
+
+        on_device: dict = {}
+        out = []
+        for e in self.engine_slice(replica, n_replicas):
+            if e.device not in on_device:
+                on_device[e.device] = [jax.device_put(m.params, e.device) for m in models]
+            out.append(on_device[e.device])
+        return out

@@ -15,15 +15,6 @@ import jax.numpy as jnp
 from .graph import LayerGraph
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: newer
-    releases return a one-element list of dicts, older ones the dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def _elementwise_fn(kind: str):
     """Representative lowering per non-conv layer kind. All of these are
     memory-bound elementwise/shuffle ops, so one op per kind is enough for
@@ -81,7 +72,7 @@ def _elementwise_cost(kind, in_shape, dtype_str):
         return 0.0, 0.0
     x = jax.ShapeDtypeStruct(tuple(in_shape), jnp.dtype(dtype_str))
     compiled = jax.jit(fn).lower(x).compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0)) + float(ca.get("transcendentals", 0.0))
     return flops, float(ca.get("bytes accessed", 0.0))
 
@@ -111,7 +102,7 @@ def _conv_cost(in_shape, kernel, stride, padding, c_out, transposed, dtype_str):
             )
 
     compiled = jax.jit(f).lower(x, w).compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))
 
 
@@ -158,7 +149,7 @@ def _fused_cost(in_shape, kernel, stride, padding, c_out, transposed, norm, act,
         return y.astype(dtype)
 
     compiled = jax.jit(f).lower(x, w, v, v).compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0)) + float(ca.get("transcendentals", 0.0))
     return flops, float(ca.get("bytes accessed", 0.0))
 
@@ -192,7 +183,7 @@ def _sppf_cost(in_shape, window, reps, dtype_str):
         return jnp.concatenate(outs, axis=-1)
 
     compiled = jax.jit(f).lower(x).compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0)) + float(ca.get("transcendentals", 0.0))
     return flops, float(ca.get("bytes accessed", 0.0))
 

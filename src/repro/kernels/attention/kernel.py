@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .._compat import load_block
+from .. import backend
 
 NEG_INF = -1e30
 
@@ -45,10 +45,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, Sk, causal, window, sof
 
     def body(kb, carry):
         m, l, acc = carry
-        # int indices can't mix with pl.ds in this jax version's NDIndexer;
-        # _compat.load_block loads them as size-1 dynamic slices and drops them
-        k = load_block(k_ref, 0, pl.ds(kb * bk, bk), 0, slice(None)).astype(jnp.float32)
-        v = load_block(v_ref, 0, pl.ds(kb * bk, bk), 0, slice(None)).astype(jnp.float32)
+        k = k_ref[0, pl.ds(kb * bk, bk), 0, :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(kb * bk, bk), 0, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         s = s * scale
         if softcap:
@@ -75,7 +73,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, Sk, causal, window, sof
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "window", "softcap", "scale", "bq", "bk", "interpret")
+    jax.jit, static_argnames=("causal", "window", "softcap", "scale", "bq", "bk")
 )
 def flash_attention(
     q,
@@ -87,7 +85,6 @@ def flash_attention(
     scale: float | None = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
 ):
     """q: (B, Sq, Hq, D); k/v: (B, Sk, Hk, D) -> (B, Sq, Hq, D)."""
     B, Sq, Hq, D = q.shape
@@ -121,5 +118,6 @@ def flash_attention(
         ],
         out_specs=pl.BlockSpec((1, bq, 1, D), lambda b, h, i: (b, i, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Sq, Hq, D), q.dtype),
-        interpret=interpret,
+        compiler_params=backend.compiler_params(3),
+        interpret=backend.interpret(),
     )(q, k, v)

@@ -24,23 +24,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .._compat import load_block
+from .. import backend
 
 
 def _phase_matmuls(x_m1, x_0, x_p1, w, th, W):
     """All four parity phases for a row tile.
 
-    x_m1/x_0/x_p1: (th, W, Cin) rows shifted -1/0/+1; w: (4,4,Cin,Cout).
-    Returns (th, 2, W, 2, Cout) = interleaved (2*th, 2*W) output tile.
+    x_m1/x_0/x_p1: (th, W, Cin) rows shifted -1/0/+1; w: (4,4,Cin,Cout)
+    already rot180-flipped (``w[::-1, ::-1]``, the kernel conv_transpose
+    applies — Mosaic has no reverse, so callers flip outside the kernel).
+    Returns the four (th, W, Cout) phases ph00, ph01, ph10, ph11 (row
+    parity, column parity).
     """
     cin = x_0.shape[-1]
     cout = w.shape[-1]
-    w = w[::-1, ::-1]  # conv_transpose applies the rot180'd kernel
 
     def shift_left(v):  # col v'+1
+        if W == 1:
+            return jnp.zeros_like(v)
         return jnp.concatenate([v[:, 1:], jnp.zeros_like(v[:, :1])], axis=1)
 
     def shift_right(v):  # col v'-1
+        if W == 1:
+            return jnp.zeros_like(v)
         return jnp.concatenate([jnp.zeros_like(v[:, :1]), v[:, :-1]], axis=1)
 
     def mm(xs, ki, kj):
@@ -58,32 +64,34 @@ def _phase_matmuls(x_m1, x_0, x_p1, w, th, W):
     ph01 = mm(shift_left(x_0), 1, 0) + mm(x_0, 1, 2) + mm(shift_left(x_m1), 3, 0) + mm(x_m1, 3, 2)
     ph10 = mm(x_p1, 0, 1) + mm(shift_right(x_p1), 0, 3) + mm(x_0, 2, 1) + mm(shift_right(x_0), 2, 3)
     ph11 = mm(shift_left(x_p1), 0, 0) + mm(x_p1, 0, 2) + mm(shift_left(x_0), 2, 0) + mm(x_0, 2, 2)
+    return ph00, ph01, ph10, ph11
 
+
+def _interleave(ph00, ph01, ph10, ph11):
+    """Four (th, W, C) parity phases -> the (th, 2, W, 2, C) output tile."""
     even = jnp.stack([ph00, ph01], axis=2)  # (th, W, 2, Cout)
     odd = jnp.stack([ph10, ph11], axis=2)
-    tile = jnp.stack([even, odd], axis=1)  # (th, 2, W, 2, Cout)
-    return tile
+    return jnp.stack([even, odd], axis=1)  # (th, 2, W, 2, Cout)
 
 
 def _deconv_kernel(x_prev_ref, x_ref, x_next_ref, w_ref, o_ref, *, th, W, n_tiles):
     i = pl.program_id(1)
-    # singleton batch axis via the shared jax-0.4.37 int-index workaround
-    x_0 = load_block(x_ref, 0, slice(None), slice(None), slice(None))  # (th, W, Cin)
+    x_0 = x_ref[0]  # (th, W, Cin)
     # row u-1: last row of the previous tile on top; masked at global top
-    prev_last = load_block(x_prev_ref, 0, slice(th - 1, th), slice(None), slice(None))
+    prev_last = x_prev_ref[0, th - 1 : th]
     prev_last = jnp.where(i > 0, prev_last, jnp.zeros_like(prev_last))
     x_m1 = jnp.concatenate([prev_last, x_0[:-1]], axis=0)
     # row u+1: first row of the next tile at the bottom; masked at bottom
-    next_first = load_block(x_next_ref, 0, slice(0, 1), slice(None), slice(None))
+    next_first = x_next_ref[0, 0:1]
     next_first = jnp.where(i < n_tiles - 1, next_first, jnp.zeros_like(next_first))
     x_p1 = jnp.concatenate([x_0[1:], next_first], axis=0)
 
-    tile = _phase_matmuls(x_m1, x_0, x_p1, w_ref[...], th, W)
+    tile = _interleave(*_phase_matmuls(x_m1, x_0, x_p1, w_ref[...], th, W))
     o_ref[0] = tile.reshape(2 * th, 2 * W, -1).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_h", "interpret"))
-def deconv2d_pallas(x, w, tile_h: int = 8, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("tile_h",))
+def deconv2d_pallas(x, w, tile_h: int = 8):
     """Stride-2, k=4, torch-padding-1 transposed conv (the Pix2Pix up-op).
 
     x: (B, H, W, Cin) -> (B, 2H, 2W, Cout). Weights (4, 4, Cin, Cout).
@@ -113,5 +121,6 @@ def deconv2d_pallas(x, w, tile_h: int = 8, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, 2 * tile_h, 2 * W, Cout), lambda b, i: (b, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 2 * H, 2 * W, Cout), x.dtype),
-        interpret=interpret,
-    )(x, x, x, w)
+        compiler_params=backend.compiler_params(2),
+        interpret=backend.interpret(),
+    )(x, x, x, w[::-1, ::-1])

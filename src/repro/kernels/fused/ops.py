@@ -1,10 +1,8 @@
-"""jit wrappers for the fused serving blocks, with reference fallback.
+"""jit wrappers for the fused serving blocks.
 
-The Pallas kernels compute norm statistics per sample (grid (B,)) — exact
-for instance/group norm at any batch and for batch norm at B == 1. A
-B > 1 batch-norm call (merged micro-batches never hit this: only
-batch-independent models merge) falls back to the jnp reference, which is
-still one fused jit region under XLA.
+Every call runs the Pallas kernel (compiled on TPU, interpreted
+elsewhere — ``kernels.backend``); batch norm at B > 1 takes its batch
+statistics inside the kernel, so no shape falls back to the reference.
 """
 from __future__ import annotations
 
@@ -14,7 +12,6 @@ import jax
 import jax.numpy as jnp
 
 from .kernel import conv_block_pallas, deconv_block_pallas, sppf_pyramid_pallas
-from .ref import conv_block_ref, deconv_block_ref
 
 
 def _affine(x, b, gamma, beta, cout):
@@ -25,9 +22,7 @@ def _affine(x, b, gamma, beta, cout):
     return b, gamma, beta
 
 
-@functools.partial(
-    jax.jit, static_argnames=("stride", "padding", "norm", "groups", "act", "eps", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("stride", "padding", "norm", "groups", "act", "eps"))
 def conv_block(
     x,
     w,
@@ -40,22 +35,16 @@ def conv_block(
     groups: int = 1,
     act: str = "silu",
     eps: float = 1e-5,
-    interpret: bool = True,
 ):
     """Fused conv(+bias)+norm+act: (B, H, W, Cin) -> (B, Ho, Wo, Cout)."""
     b, gamma, beta = _affine(x, b, gamma, beta, w.shape[-1])
-    if norm == "batch" and x.shape[0] > 1:
-        return conv_block_ref(
-            x, w, b, gamma, beta, stride=stride, padding=padding, norm=norm,
-            groups=groups, act=act, eps=eps,
-        )
     return conv_block_pallas(
         x, w, b, gamma, beta, stride=stride, padding=padding, norm=norm,
-        groups=groups, act=act, eps=eps, interpret=interpret,
+        groups=groups, act=act, eps=eps,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("norm", "groups", "act", "eps", "interpret"))
+@functools.partial(jax.jit, static_argnames=("norm", "groups", "act", "eps"))
 def deconv_block(
     x,
     w,
@@ -66,20 +55,15 @@ def deconv_block(
     groups: int = 1,
     act: str = "relu",
     eps: float = 1e-5,
-    interpret: bool = True,
 ):
     """Fused k=4/s=2 deconv + crop (+bias) + norm + act: -> (B, 2H, 2W, Cout)."""
     b, gamma, beta = _affine(x, b, gamma, beta, w.shape[-1])
-    if norm == "batch" and x.shape[0] > 1:
-        return deconv_block_ref(x, w, b, gamma, beta, norm=norm, groups=groups, act=act, eps=eps)
-    return deconv_block_pallas(
-        x, w, b, gamma, beta, norm=norm, groups=groups, act=act, eps=eps, interpret=interpret
-    )
+    return deconv_block_pallas(x, w, b, gamma, beta, norm=norm, groups=groups, act=act, eps=eps)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "reps", "interpret"))
-def sppf_pyramid(x, window: int = 5, reps: int = 3, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("window", "reps"))
+def sppf_pyramid(x, window: int = 5, reps: int = 3):
     """Fused SPPF pool pyramid + concat: (B, H, W, C) -> (B, H, W, (reps+1)*C).
 
-    Max/concat only — exact at any batch, no reference fallback needed."""
-    return sppf_pyramid_pallas(x, window=window, reps=reps, interpret=interpret)
+    Max/concat only — exact at any batch."""
+    return sppf_pyramid_pallas(x, window=window, reps=reps)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import load_block
+from .. import backend
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *, chunk, P, N):
@@ -30,12 +30,11 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *, chunk, 
     def _init():
         state_ref[...] = jnp.zeros((P, N), jnp.float32)
 
-    # singleton grid axes via the shared jax-0.4.37 int-index workaround
-    x = load_block(x_ref, 0, slice(None), 0, slice(None)).astype(jnp.float32)  # (L, P)
-    dt = load_block(dt_ref, 0, slice(None), 0).astype(jnp.float32)  # (L,)
+    x = x_ref[0, :, 0, :].astype(jnp.float32)  # (L, P)
+    dt = dt_ref[0, :, 0].astype(jnp.float32)  # (L,)
     a = a_ref[0].astype(jnp.float32)  # scalar (per head)
-    bmat = load_block(b_ref, 0, slice(None), 0, slice(None)).astype(jnp.float32)  # (L, N)
-    cmat = load_block(c_ref, 0, slice(None), 0, slice(None)).astype(jnp.float32)  # (L, N)
+    bmat = b_ref[0, :, 0, :].astype(jnp.float32)  # (L, N)
+    cmat = c_ref[0, :, 0, :].astype(jnp.float32)  # (L, N)
 
     dA = dt * a  # (L,)
     dA_cum = jnp.cumsum(dA)  # (L,)
@@ -63,8 +62,8 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *, chunk, 
     state_ref[...] = h_prev * jnp.exp(dA_cum[-1]) + delta
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_pallas(x, dt, A, B, C, chunk: int = 128, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssd_pallas(x, dt, A, B, C, chunk: int = 128):
     """x: (b, s, h, p); dt: (b, s, h); A: (h,); B/C: (b, s, g, n) -> y like x."""
     b, s, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
@@ -87,9 +86,8 @@ def ssd_pallas(x, dt, A, B, C, chunk: int = 128, interpret: bool = True):
         out_shape=jax.ShapeDtypeStruct((b, s, h, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-        if not interpret
-        else None,
-        interpret=interpret,
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=backend.VMEM_LIMIT_BYTES,
+        ),
+        interpret=backend.interpret(),
     )(x, dt, A, B, C)

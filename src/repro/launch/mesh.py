@@ -16,14 +16,12 @@ import jax
 
 
 def _make_mesh(shape, axes, devices):
-    """jax.make_mesh across versions: ``axis_types`` (and AxisType) only
-    exist on newer jax — everything downstream uses explicit
-    NamedShardings, for which the default (auto) axis types are right."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, devices=devices, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes, devices=devices)
+    """``jax.make_mesh`` with ``Auto`` axes: everything downstream uses
+    explicit NamedShardings and ``with_sharding_constraint``, which the
+    default ``Explicit`` axes refuse."""
+    return jax.make_mesh(
+        shape, axes, devices=devices, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

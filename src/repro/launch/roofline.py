@@ -204,9 +204,8 @@ def analyze(arch, shape, mesh_name, n_chips, compiled, model_flops, analytic_tot
     this backend, so the measured HLO flops only *calibrate* a loop
     correction factor that re-scales the byte / collective terms (the same
     loops hold those bytes)."""
-    from ..core.profiler import cost_analysis_dict
 
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     hlo_flops = float(ca.get("flops", 0.0))
     if analytic_total is None:
         analytic_total = hlo_flops * n_chips

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import get_arch, build_model
+from . import compile_cache
 
 
 def run_streams(args) -> None:
@@ -298,6 +299,7 @@ def main():
         help="enable the graceful-degradation admission ladder (shed resolution -> shed staging -> drop)",
     )
     args = ap.parse_args()
+    compile_cache.enable()
     if args.traffic is not None and args.deadline_ms is None:
         args.deadline_ms = 100.0
 

@@ -87,7 +87,7 @@ class UpBlockDeconv(Module):
 
     ``backend="pallas"`` routes padded/cropping modes through the
     phase-decomposed TPU kernel (repro.kernels.deconv) — one fused op,
-    crop folded into indexing (interpret mode on CPU)."""
+    crop folded into indexing."""
 
     c_in: int
     c_out: int
@@ -107,7 +107,7 @@ class UpBlockDeconv(Module):
             from ..kernels.deconv.ops import deconv2d
 
             b = p["deconv"].get("b") if self.use_bias else None
-            return deconv2d(x, p["deconv"]["w"], b=b, stride=2, padding=1, interpret=True)
+            return deconv2d(x, p["deconv"]["w"], b=b, stride=2, padding=1)
         if self.mode == "padded":
             return ConvTranspose2D(self.c_in, self.c_out, 4, 2, padding=1, use_bias=self.use_bias)(p["deconv"], x)
         y = ConvTranspose2D(self.c_in, self.c_out, 4, 2, padding=0, use_bias=self.use_bias)(p["deconv"], x)

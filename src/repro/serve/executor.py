@@ -186,6 +186,7 @@ class StreamExecutor:
         on_segment: Callable[[SegmentObservation], None] | None = None,
         segment_delay_fn: Callable[[PlanSegment], float] | None = None,
         batching: BatchConfig | None = None,
+        engine_params: list[list] | None = None,
     ):
         ir = _as_plan_ir(plan, engine_names)
         if len(models) != ir.n_models:
@@ -214,6 +215,9 @@ class StreamExecutor:
             self.merge_batches = list(merge_batches)
         n_engines = ir.n_engines
         self.place_fns = place_fns or [lambda x: x] * n_engines
+        # per-engine weight copies on the engine's device (``DevicePool.
+        # place_params``); None = every engine runs on the models' params
+        self.engine_params = engine_params
         self.engine_names = list(engine_names) if engine_names else list(ir.engine_names)
         self.model_labels = model_labels or [m.name for m in models]
         self.queues = [FrameQueue(max_queue) for _ in streams]
@@ -377,7 +381,8 @@ class StreamExecutor:
                         key = (mi, seg.lo, seg.hi, impl, bucket)
                         if key not in self._seg_fns:
                             self._seg_fns[key] = self._make_runner(mi, seg.lo, seg.hi, impl)
-                        state = self._seg_fns[key](model.params, state)
+                        state = self.place_fns[seg.engine](state)
+                        state = self._seg_fns[key](self._params(mi, seg.engine), state)
                         warmed += 1
                     jax.block_until_ready(state)
         return warmed
@@ -423,6 +428,12 @@ class StreamExecutor:
         if acc is None:
             acc = self._wait_acc[engine] = [0.0, 0.0, 0.0]
         acc[slot] += dt
+
+    def _params(self, mi: int, engine: int):
+        """Model ``mi``'s params as placed for ``engine``."""
+        if self.engine_params is None:
+            return self.models[mi].params
+        return self.engine_params[engine][mi]
 
     def _make_runner(self, mi: int, lo: int, hi: int, impl: str = "xla") -> Callable:
         model = self.models[mi]
@@ -481,7 +492,7 @@ class StreamExecutor:
         self._charge_wait(eng, 1, t1 - t0)
         bucket = flight.bucket or flight.valid or _leading(state)
         flight.state = self._segment_runner(flight.model_index, seg, bucket)(
-            self.models[flight.model_index].params, state
+            self._params(flight.model_index, eng), state
         )
         self._charge_wait(eng, 0, time.perf_counter() - t1)
         d = 0.0
@@ -699,7 +710,11 @@ class StreamExecutor:
         for si, fid, frame, t_sub, degrade in picked:
             size = int(frame.shape[0]) if hasattr(frame, "shape") and frame.shape else 1
             members.append(FlightMember(si, fid, size, t_sub, self.tick_count, degrade=degrade))
-            states.append(model.init_state(frame))
+            state = model.init_state(frame)
+            if self._donate:
+                # segments donate their input state: never the caller's frame
+                state = jax.tree.map(jnp.copy, state)
+            states.append(state)
         route = self.plan.route(mi)
         rev = self.plan.revision
         # Degraded frames never merge: level-1 frames have shed shapes,

@@ -220,7 +220,15 @@ def build_server(
     ``calib_sync_every`` front ticks, checkpointing atomically to
     ``calibration_path`` (which also warm-starts workers on spawn). Call
     ``bundle.close()`` (or use the bundle as a context manager) to shut
-    the workers down."""
+    the workers down. On a TPU backend ``workers > 0`` raises before
+    anything is spawned: this process already holds the chip, and a chip
+    belongs to one process."""
+    if workers and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "build_server(workers>0) needs one process per accelerator, but this "
+            "process already holds the TPU; use replicas=R to serve R replicas "
+            "from this process"
+        )
     if workers and replicas > 1:
         raise ValueError(
             "workers and replicas are mutually exclusive: a multi-process fleet "

@@ -8,8 +8,9 @@ the *same* ``PlanIR`` (one ``core.plan`` solved once over the per-replica
 engine slice — the slices are value-identical, only their device binding
 differs, so one solution serves every replica and the jit caches on the
 shared models mean one compilation fleet-wide). A ``DevicePool``
-(``core.engine``) supplies each replica's engine slice and the
-``jax.device_put`` placement closures its executor applies per segment;
+(``core.engine``) supplies each replica's engine slice, the
+``jax.device_put`` placement closures its executor applies per segment,
+and the weights, copied once at build time onto each engine's device;
 on 1-device hosts (CPU CI) every replica binds the virtual GPU/DLA pair
 to the single device and placement collapses to identity.
 
@@ -266,6 +267,7 @@ class FleetServer:
                 merge_batches=merge_batches,
                 batching=batching,
                 place_fns=pool.place_fns(r, replicas),
+                engine_params=pool.place_params(models, r, replicas),
                 dispatch=dispatch,
                 jit_segments=jit_segments,
                 replanner=replanners[r] if replanners is not None else None,

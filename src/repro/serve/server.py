@@ -54,6 +54,7 @@ class MultiStreamServer:
         admission: AdmissionConfig | None = None,
         resolution_flexible: bool | list[bool] = False,
         batching: BatchConfig | None = None,
+        engine_params: list[list] | None = None,
     ):
         self.executor = StreamExecutor(
             models,
@@ -66,6 +67,7 @@ class MultiStreamServer:
             dispatch=dispatch,
             jit_segments=jit_segments,
             batching=batching,
+            engine_params=engine_params,
         )
         self.replanner = replanner
         self.metrics = ServeMetrics(

@@ -27,9 +27,8 @@ def test_analytic_vs_hlo_forward_smoke():
         .lower(ab, jax.ShapeDtypeStruct((B, S), jnp.int32))
         .compile()
     )
-    from repro.core.profiler import cost_analysis_dict
 
-    hlo = float(cost_analysis_dict(c)["flops"])
+    hlo = float(c.cost_analysis()["flops"])
     analytic = B * S * fwd_flops_per_token(cfg, S, "train")
     # the analytic model counts causal-HALF attention (what a flash kernel
     # executes); XLA's dense-masked path does the full S^2 — so analytic may

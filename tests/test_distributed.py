@@ -111,7 +111,7 @@ def test_compressed_pod_gradients():
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.dist import make_compressed_dp_grad_fn, zeros_like_error
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 def loss_fn(params, batch):
     return jnp.mean((batch["x"] @ params["w"] - batch["y"])**2)
 params = {"w": jnp.ones((8, 4))}

@@ -87,6 +87,43 @@ def test_device_pool_validates_inputs(engines):
         DevicePool((dla, gpu), devices=[])
 
 
+def test_fleet_replicas_keep_weights_and_outputs_on_their_devices():
+    """On a 4-device host every replica's engines run on weight copies
+    placed once at build time on that replica's devices, and its outputs
+    stay there; at 2 replicas each replica's two engines sit on distinct
+    devices. Outputs match one replica's."""
+    from conftest import run_subprocess
+
+    code = """
+import jax, jax.numpy as jnp, numpy as np
+from repro.serve import build_server
+x = jnp.asarray(np.random.default_rng(0).normal(size=(1, 32, 32, 3)), jnp.float32)
+outs = {}
+for r in (1, 2, 4):
+    b = build_server(img=32, base=8, n_pix=2, n_yolo=1, seed=0, replicas=r)
+    for s in b.streams:
+        assert b.server.offer(s.name, x) == "admit"
+    outs[r] = b.server.drain()
+    if r == 1:
+        continue
+    for k, srv in enumerate(b.server.servers):
+        devs = set(b.server.pool.replica_devices(k, r))
+        ep = srv.executor.engine_params
+        per_engine = [{d for leaf in jax.tree.leaves(p) for d in leaf.devices()} for p in ep]
+        assert all(d <= devs for d in per_engine), (k, per_engine, devs)
+        assert len(set().union(*per_engine)) == (2 if r == 2 else 1), per_engine
+        for vals in srv.executor.outputs.values():
+            for v in vals:
+                assert all(leaf.devices() <= devs for leaf in jax.tree.leaves(v))
+    for name, got in outs[r].items():
+        for g, w in zip(got, outs[1][name], strict=True):
+            for gl, wl in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+                np.testing.assert_allclose(np.asarray(gl), np.asarray(wl), atol=1e-5)
+print("OK")
+"""
+    assert run_subprocess(code, devices=4).strip().endswith("OK")
+
+
 # ---- FleetRouter -----------------------------------------------------------
 
 

@@ -30,11 +30,14 @@ CONV_CASES = [
     ((1, 32, 32, 16), 3, 2, 1, 32, "batch", "silu"),
     ((2, 16, 16, 8), 4, 2, 1, 16, "instance", "lrelu"),  # B>1 per-sample stats
     ((1, 16, 16, 8), 4, 2, 1, 16, "group", "lrelu"),
+    ((1, 16, 16, 16), 3, 1, 1, 16, "batch", "silu"),  # stride 1, no space-to-depth
+    ((1, 8, 8, 32), 1, 1, 0, 256, "batch", "silu"),  # two 128-lane channel tiles
 ]
 DECONV_CASES = [
     ((1, 4, 4, 64), 32, "batch", "relu"),
     ((1, 8, 8, 64), 16, "batch", "relu"),
     ((2, 8, 8, 16), 8, "instance", "relu"),
+    ((2, 8, 8, 16), 8, "batch", "relu"),  # B>1: statistics over the batch
 ]
 
 
@@ -117,8 +120,8 @@ def test_yolo_fine_granularity_pins_sppf_variant_group():
 
 
 def test_conv_block_batchnorm_b2_matches_ref():
-    # B>1 batch norm takes cross-sample statistics: the wrapper must route
-    # to the fused jnp reference, not the per-sample Pallas kernel
+    # B>1 batch norm takes cross-sample statistics: the kernel holds the
+    # whole batch in one grid step and normalises over it
     x = jax.random.normal(jax.random.key(0), (2, 16, 16, 8))
     w, b, gamma, beta = _params(jax.random.key(1), 8, 16, 4)
     got = conv_block(x, w, b, gamma, beta, stride=2, padding=1, norm="batch", act="lrelu")

@@ -381,3 +381,43 @@ def test_percentile_nearest_rank():
     assert percentile(xs, 100) == 100.0
     assert percentile([3.0], 50) == 3.0
     assert np.isnan(percentile([], 50))
+
+
+def test_build_server_refuses_workers_on_tpu_before_spawning(monkeypatch):
+    """A chip belongs to one process: on a TPU backend a worker fleet
+    would hang on libtpu, so build_server raises before anything runs."""
+    from repro.serve import build_server, facade
+
+    def no_build(*a, **k):
+        raise AssertionError("models were built before the workers check")
+
+    monkeypatch.setattr(facade.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(facade, "_build_pix_yolo_models", no_build)
+    with pytest.raises(RuntimeError, match="one process per accelerator"):
+        build_server(img=32, base=8, workers=2)
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_dir_follows_env(env_set, tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the helper leaves JAX on it and
+    sets no directory; unset, the cache goes to the fixed in-checkout path."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    if env_set:
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+    else:
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+    try:
+        path = compile_cache.enable()
+        if env_set:
+            assert path == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert path == str(compile_cache.CHECKOUT_DIR)
+            assert jax.config.jax_compilation_cache_dir == path
+            assert (compile_cache.CHECKOUT_DIR.parent / "chip_smoke.py").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
