@@ -16,12 +16,20 @@ hooks engine-boundary transfers (``jax.device_put`` to a submesh on TPU).
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Any, Callable
 
 import jax
 
 from .graph import LayerGraph
 from .plan_ir import PlanIR
+
+
+def executable_label(model_name: str) -> str:
+    """A model's name with every run of characters other than letters,
+    digits and ``_`` made one ``_``: the first part of its segment
+    executables' names."""
+    return re.sub(r"[^0-9A-Za-z_]+", "_", model_name).strip("_")
 
 
 @dataclasses.dataclass
@@ -111,15 +119,25 @@ class StagedModel:
     def segment_fn(self, lo, hi, impl: str = "xla"):
         """Pure ``(params, state) -> state`` over the ops executing layers
         ``[lo, hi)`` — the form ``jax.jit`` (with state-buffer donation)
-        accepts."""
+        accepts. Each op runs under ``jax.named_scope(<op name>)``, so a
+        device trace names the op each operation came from; the function is
+        named ``segment_name(lo, hi, impl)``."""
         ops = self.segment_ops(lo, hi, impl)
 
         def f(params, state):
-            for _, fn in ops:
-                state = fn(params, state)
+            for name, fn in ops:
+                with jax.named_scope(name):
+                    state = fn(params, state)
             return state
 
+        f.__name__ = f.__qualname__ = self.segment_name(lo, hi, impl)
         return f
+
+    def segment_name(self, lo, hi, impl: str = "xla") -> str:
+        """``<label>.<lo>_<hi>.<impl>`` (``executable_label`` of the model's
+        name): a segment executable compiles as ``jit_<this>``, stable
+        across rebuilds and distinct per segment."""
+        return f"{executable_label(self.name)}.{lo}_{hi}.{impl}"
 
     def jitted_segment_fn(self, lo, hi, donate: bool = False, impl: str = "xla"):
         """Fused one-executable form of ``segment_fn``, cached on the model

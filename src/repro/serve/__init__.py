@@ -36,6 +36,7 @@ from .multiproc import (
 from .replanner import ReplanConfig, ReplanEvent, Replanner
 from .server import MultiStreamServer, Request
 from .streams import FrameQueue, StreamSpec
+from .tracing import SpanRecorder
 from .traffic import (
     SLOPolicy,
     TrafficConfig,

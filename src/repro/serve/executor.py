@@ -58,7 +58,20 @@ wall (compute + stall) so the drift detector sees the slowdown.
 call — the pre-overlap behaviour kept as the measurable baseline. Both
 modes run the exact same op sequence per frame as ``StagedModel.run_all``.
 Per-tick host wall/blocked time is recorded in ``tick_stats`` (see
-``metrics.TickStats.overlap_efficiency``).
+``metrics.TickStats.overlap_efficiency``) on every tick.
+
+**Spans**: the executor records its work through ``tracer``, a
+``serve.tracing.SpanRecorder`` (the server's, shared), which is off unless
+enabled: ``executor.advance`` (in-flight segments, deepest first),
+``executor.admit`` per model with child ``executor.stage_in`` (frame
+upload, donation copy, concatenation, padding), ``executor.dispatch`` per
+segment call with child ``executor.place``, ``executor.resolve`` per
+finished flight with child ``executor.block``, and ``executor.on_tick``.
+``TickStats.engine_wait`` is a view over the tick's ``dispatch``,
+``place`` and ``block`` spans, present only while the recorder is on.
+Every completion carries its frame's submit and admission stamps, so its
+queue wait (admission minus submit, coalescer hold included) is always
+known.
 
 Micro-batching (``microbatch > 1``) admits up to that many same-model
 frames per tick; with ``merge_batches`` the group is concatenated along
@@ -80,6 +93,10 @@ from ..core.scheduler import NModelPlan
 from .batching import BatchConfig
 from .metrics import TickStats
 from .streams import FrameQueue, StreamSpec
+from .tracing import SpanRecorder
+
+# engine_wait slot of each span that feeds it: (issue_s, transfer_s, resolve_s)
+_WAIT_SLOT = {"executor.dispatch": 0, "executor.place": 1, "executor.block": 2}
 
 
 @dataclasses.dataclass
@@ -105,6 +122,9 @@ class Flight:
     bucket: int = 0  # padded leading-axis extent (the compiled bucket); 0 = valid
     held: bool = False  # the coalescer delayed this flight waiting for co-riders
     t_issue: float = 0.0  # admission wall clock (feeds the service-time EMA)
+    t_admit: float = 0.0  # wall clock at which the members left their queues
+    uid: int = 0  # the executor's flight number
+    frames: str = ""  # members' ``<stream>/<frame id>`` keys, while tracing
 
 
 @dataclasses.dataclass
@@ -118,6 +138,19 @@ class Completion:
     degrade: int = 0  # admission degrade level the frame ran under
     batch: int = 1  # real frames in the flight this frame rode in (occupancy)
     held: bool = False  # the flight was held by the coalescer before running
+    t_submit: float = 0.0  # wall clock at submit
+    t_admit: float = 0.0  # wall clock at which the frame left its queue for a flight
+    t_done: float = 0.0  # wall clock at completion
+
+    @property
+    def queue_wait_s(self) -> float:
+        """Submit to admission: time in the stream queue, coalescer hold included."""
+        return self.t_admit - self.t_submit
+
+    @property
+    def service_s(self) -> float:
+        """Admission to completion."""
+        return self.t_done - self.t_admit
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,6 +220,7 @@ class StreamExecutor:
         segment_delay_fn: Callable[[PlanSegment], float] | None = None,
         batching: BatchConfig | None = None,
         engine_params: list[list] | None = None,
+        tracer: SpanRecorder | None = None,
     ):
         ir = _as_plan_ir(plan, engine_names)
         if len(models) != ir.n_models:
@@ -269,9 +303,8 @@ class StreamExecutor:
         # the self-calibrating "expected batched segment time" the hold
         # decision compares slack against
         self._svc_ema: dict[tuple[int, int], float] = {}
-        # per-engine host-time breakdown for the current tick (satellite
-        # diagnostic): engine index -> [issue_s, transfer_s, resolve_s]
-        self._wait_acc: dict[int, list[float]] = {}
+        self.tracer = tracer if tracer is not None else SpanRecorder()
+        self._flights = 0  # flights admitted so far (their uids)
 
     # -- submission ---------------------------------------------------------
 
@@ -411,23 +444,32 @@ class StreamExecutor:
 
     def _block(self, x, engine: int | None = None):
         """block_until_ready with the wait charged to this tick's stats
-        (and, when ``engine`` is given, to that engine's resolve-wait in
-        the per-engine breakdown)."""
-        t0 = time.perf_counter()
-        x = jax.block_until_ready(x)
-        dt = time.perf_counter() - t0
-        self._blocked_s += dt
-        if engine is not None:
-            self._charge_wait(engine, 2, dt)
+        (and, while tracing, to an ``executor.block`` span on ``engine``)."""
+        with self.tracer.span("executor.block", engine=self._engine_name(engine)):
+            t0 = time.perf_counter()
+            x = jax.block_until_ready(x)
+            self._blocked_s += time.perf_counter() - t0
         return x
 
-    def _charge_wait(self, engine: int, slot: int, dt: float):
-        """Accrue host time to one engine's (issue, transfer, resolve)
-        breakdown for the current tick."""
-        acc = self._wait_acc.get(engine)
-        if acc is None:
-            acc = self._wait_acc[engine] = [0.0, 0.0, 0.0]
-        acc[slot] += dt
+    def _engine_name(self, engine: int | None) -> str:
+        return "" if engine is None else self.engine_names[engine]
+
+    def _engine_wait(self, mark: int) -> dict | None:
+        """Per-engine ``(issue_s, transfer_s, resolve_s)`` of the spans
+        ended since ``mark``: ``executor.dispatch`` self time (the segment
+        call without its placement), ``executor.place`` and
+        ``executor.block``. None while the recorder is off."""
+        if not self.tracer.enabled:
+            return None
+        acc: dict[str, list[float]] = {}
+        for s in self.tracer.since(mark):
+            slot = _WAIT_SLOT.get(s.name)
+            engine = s.attrs.get("engine")
+            if slot is None or not engine:
+                continue
+            a = acc.setdefault(engine, [0.0, 0.0, 0.0])
+            a[slot] += s.self_s if slot == 0 else s.dur
+        return {e: tuple(a) for e, a in acc.items()} or None
 
     def _params(self, mi: int, engine: int):
         """Model ``mi``'s params as placed for ``engine``."""
@@ -486,15 +528,16 @@ class StreamExecutor:
         observation (the live cost feedback)."""
         seg = flight.route[flight.stage]
         eng = seg.engine
-        t0 = time.perf_counter()
-        state = self.place_fns[eng](flight.state)
-        t1 = time.perf_counter()
-        self._charge_wait(eng, 1, t1 - t0)
-        bucket = flight.bucket or flight.valid or _leading(state)
-        flight.state = self._segment_runner(flight.model_index, seg, bucket)(
-            self._params(flight.model_index, eng), state
-        )
-        self._charge_wait(eng, 0, time.perf_counter() - t1)
+        mi = flight.model_index
+        t0 = time.perf_counter() if self._profiling_tick else 0.0
+        tr = self.tracer
+        with tr.span("executor.dispatch", model=self.model_labels[mi], lo=seg.lo, hi=seg.hi,
+                     engine=self.engine_names[eng], bucket=flight.bucket, flight=flight.uid,
+                     frames=flight.frames):
+            with tr.span("executor.place", engine=self.engine_names[eng]):
+                state = self.place_fns[eng](flight.state)
+            bucket = flight.bucket or flight.valid or _leading(state)
+            flight.state = self._segment_runner(mi, seg, bucket)(self._params(mi, eng), state)
         d = 0.0
         if self.segment_delay_fn is not None:
             d = self.segment_delay_fn(seg)
@@ -578,6 +621,9 @@ class StreamExecutor:
                     degrade=m.degrade,
                     batch=valid,
                     held=flight.held,
+                    t_submit=m.t_submit,
+                    t_admit=flight.t_admit,
+                    t_done=now,
                 )
             )
 
@@ -649,7 +695,6 @@ class StreamExecutor:
         model's streams merge into one flight, padded to the power-of-two
         bucket; a partial bucket may *hold* (frames stay queued) while
         every member's slack allows it — see ``_should_hold``."""
-        model = self.models[mi]
         stream_idxs = self._streams_of[mi]
         if not stream_idxs:
             return []
@@ -706,6 +751,30 @@ class StreamExecutor:
             fid, frame, t_sub, degrade = self.queues[si].pop()
             picked.append((si, fid, frame, t_sub, degrade))
         self._rr[mi] = (start + len(picked)) % n
+        with self.tracer.span("executor.stage_in") as sp:
+            flights = self._stage_in(mi, picked, held, coalesce)
+            for flight in flights:
+                flight.t_admit = now
+            if self.tracer.enabled:
+                sp.note(flight=";".join(str(f.uid) for f in flights),
+                        frames=";".join(f.frames for f in flights),
+                        bucket=";".join(str(f.bucket) for f in flights))
+        done = []
+        for flight in flights:
+            self._run_segment(flight)
+            if flight.stage == len(flight.route):
+                done.append(flight)
+            else:
+                self.in_flight.append(flight)
+        return done
+
+    def _stage_in(self, mi: int, picked: list, held: bool, coalesce: bool) -> list[Flight]:
+        """The flights of the frames ``picked`` off model ``mi``'s queues:
+        each frame's initial state on the device (copied where segments
+        donate), clean frames concatenated and padded to their bucket
+        where the model merges, degraded frames in flights of their own."""
+        model = self.models[mi]
+        bc = self.batching
         members, states = [], []
         for si, fid, frame, t_sub, degrade in picked:
             size = int(frame.shape[0]) if hasattr(frame, "shape") and frame.shape else 1
@@ -779,16 +848,15 @@ class StreamExecutor:
                 )
             )
         for flight in flights:
+            self._flights += 1
+            flight.uid = self._flights
+            if self.tracer.enabled:
+                flight.frames = ";".join(
+                    f"{self.streams[m.stream_index].name}/{m.frame_id}" for m in flight.members
+                )
             self._note_state_struct(mi, flight.state)
             flight.t_issue = time.perf_counter()
-        done = []
-        for flight in flights:
-            self._run_segment(flight)
-            if flight.stage == len(flight.route):
-                done.append(flight)
-            else:
-                self.in_flight.append(flight)
-        return done
+        return flights
 
     def tick(self):
         """One steady-state cycle. Issue phase: advance every in-flight
@@ -796,9 +864,10 @@ class StreamExecutor:
         stage 0 — all dispatched without waiting in overlapped mode.
         Resolve phase: block on (only) the frames whose route finished."""
         t_start = time.perf_counter()
+        tr = self.tracer
+        mark = tr.recorded
         self._blocked_s = 0.0
         self._segments_issued = 0
-        self._wait_acc = {}
         self._profiling_tick = self.profile_every > 0 and self.tick_count % self.profile_every == 0
         if self._profiling_tick and self.in_flight:
             # drain the async dispatch queue before timing anything: without
@@ -812,17 +881,19 @@ class StreamExecutor:
         # deepest stage first; route lengths may differ across plan
         # revisions, so the depth bound comes from the live flights
         max_stages = max((len(f.route) for f in self.in_flight), default=1)
-        for stage in range(max_stages - 1, 0, -1):
-            for mi in range(len(self.models)):
-                for flight in [
-                    f for f in self.in_flight if f.model_index == mi and f.stage == stage
-                ]:
-                    self._run_segment(flight)
-                    if flight.stage == len(flight.route):
-                        done.append(flight)
-                        self.in_flight.remove(flight)
+        with tr.span("executor.advance"):
+            for stage in range(max_stages - 1, 0, -1):
+                for mi in range(len(self.models)):
+                    for flight in [
+                        f for f in self.in_flight if f.model_index == mi and f.stage == stage
+                    ]:
+                        self._run_segment(flight)
+                        if flight.stage == len(flight.route):
+                            done.append(flight)
+                            self.in_flight.remove(flight)
         for mi in range(len(self.models)):
-            done.extend(self._admit(mi))
+            with tr.span("executor.admit", model=self.model_labels[mi]):
+                done.extend(self._admit(mi))
         if self._tick_delay:
             # pay the slowest engine's accrued stall once per tick, before
             # resolving: concurrent engines' stalls overlap each other and
@@ -830,24 +901,24 @@ class StreamExecutor:
             time.sleep(max(self._tick_delay.values()))
             self._tick_delay.clear()
         for flight in done:
-            self._complete(flight)
+            with tr.span("executor.resolve", model=self.model_labels[flight.model_index],
+                         flight=flight.uid, frames=flight.frames):
+                self._complete(flight)
         self.tick_stats.append(
             TickStats(
                 tick=self.tick_count,
                 wall_s=time.perf_counter() - t_start,
                 blocked_s=self._blocked_s,
                 segments=self._segments_issued,
-                engine_wait={
-                    self.engine_names[e]: tuple(acc) for e, acc in self._wait_acc.items()
-                }
-                or None,
+                engine_wait=self._engine_wait(mark),
             )
         )
         self.tick_count += 1
         if self.on_tick is not None:
             # frame boundary: the replanner's chance to observe drift and
             # hot-swap before the next admission
-            self.on_tick(self)
+            with tr.span("executor.on_tick"):
+                self.on_tick(self)
 
     def run_until_drained(self, max_ticks: int = 100000):
         while self.pending:

@@ -25,17 +25,22 @@ from collections import deque
 class TickStats:
     """Host-side timing of one executor tick.
 
-    ``engine_wait`` is the per-engine host-time breakdown of the tick:
-    ``{engine_name: (issue_s, transfer_s, resolve_s)}`` — dispatch time
-    spent issuing that engine's segments, time placing states onto it,
-    and time blocked waiting for its results. Resolve-wait dominating the
-    tick is the no-overlap signature the coalescer attacks."""
+    ``wall_s`` and ``blocked_s`` are timed on every tick. ``engine_wait``
+    is the per-engine host-time breakdown of the tick, ``{engine_name:
+    (issue_s, transfer_s, resolve_s)}`` — dispatch time spent issuing that
+    engine's segments, time placing states onto it, and time blocked
+    waiting for its results. It is a view over the tick's
+    ``executor.dispatch`` (self time), ``executor.place`` and
+    ``executor.block`` spans, present only while the executor's span
+    recorder is on (``serve.tracing``); None otherwise. Resolve-wait
+    dominating the tick is the no-overlap signature the coalescer
+    attacks."""
 
     tick: int
     wall_s: float
     blocked_s: float  # time inside block_until_ready during this tick
     segments: int  # engine segment calls issued this tick
-    engine_wait: dict | None = None  # engine -> (issue_s, transfer_s, resolve_s)
+    engine_wait: dict | None = None  # engine -> (issue_s, transfer_s, resolve_s), while tracing
 
     @property
     def overlap_efficiency(self) -> float:
@@ -245,6 +250,10 @@ class ServeMetrics:
         self.batch_occupancy: dict[int, int] = {}
         self.held_frames = 0
         self.held_then_missed = 0
+        # queue wait: submit to admission into a flight, summed over the
+        # completions that carry it
+        self.queue_wait_s = 0.0
+        self.queue_frames = 0
 
     def _tier(self, stream: str) -> TierMetrics:
         slo = self.slos.get(stream)
@@ -255,7 +264,7 @@ class ServeMetrics:
         return tm
 
     def record(self, stream: str, latency_s: float, degrade: int = 0,
-               batch: int = 1, held: bool = False):
+               batch: int = 1, held: bool = False, queue_wait_s: float | None = None):
         slo = self.slos.get(stream)
         met = slo is None or latency_s <= slo.deadline_s
         self.streams[stream].record(latency_s, met_slo=met)
@@ -271,6 +280,9 @@ class ServeMetrics:
             self.held_frames += 1
             if not met:
                 self.held_then_missed += 1
+        if queue_wait_s is not None:
+            self.queue_wait_s += queue_wait_s
+            self.queue_frames += 1
 
     def mean_effective_batch(self) -> float:
         """Frame-weighted mean of the batch each completion rode in."""
@@ -326,6 +338,9 @@ class ServeMetrics:
                 "held_frames": self.held_frames,
                 "held_then_missed": self.held_then_missed,
             },
+            # mean submit-to-admission wait; None before any completion carries one
+            "queue": {"frames": self.queue_frames, "wait_ms_mean": (
+                1e3 * self.queue_wait_s / self.queue_frames if self.queue_frames else None)},
             "per_stream": {n: m.summary() for n, m in self.streams.items()},
         }
         if self.slos:
@@ -376,6 +391,8 @@ class ServeMetrics:
             "batch_occupancy": {str(b): n for b, n in self.batch_occupancy.items()},
             "held_frames": self.held_frames,
             "held_then_missed": self.held_then_missed,
+            "queue_wait_s": self.queue_wait_s,
+            "queue_frames": self.queue_frames,
         }
 
 
@@ -420,6 +437,8 @@ def metrics_from_payload(payload: dict) -> ServeMetrics:
     m.batch_occupancy = {int(b): int(n) for b, n in payload.get("batch_occupancy", {}).items()}
     m.held_frames = int(payload.get("held_frames", 0))
     m.held_then_missed = int(payload.get("held_then_missed", 0))
+    m.queue_wait_s = float(payload.get("queue_wait_s", 0.0))
+    m.queue_frames = int(payload.get("queue_frames", 0))
     return m
 
 
@@ -475,6 +494,8 @@ def merge_metrics(replica_metrics) -> "ServeMetrics":
             agg.batch_occupancy[b] = agg.batch_occupancy.get(b, 0) + c
         agg.held_frames += m.held_frames
         agg.held_then_missed += m.held_then_missed
+        agg.queue_wait_s += m.queue_wait_s
+        agg.queue_frames += m.queue_frames
     return agg
 
 

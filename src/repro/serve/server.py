@@ -13,6 +13,12 @@ Pass a ``serve.Replanner`` to close the online re-planning loop: the
 server wires it into the executor (profiled ticks feed the ``OnlineCost``
 EMA, the drift detector hot-swaps plans at frame boundaries) and folds
 its state — per-engine scales, drift, swap events — into ``report()``.
+
+The server owns one ``serve.tracing.SpanRecorder``, ``tracer``, shared
+with its executor and off until ``tracer.enable()``: it records
+``serve.offer`` (stream, decision, frame), ``serve.tick`` and
+``serve.fold`` around the executor's own spans, and ``report()`` carries
+its counters and long spans under ``"spans"`` while it is on.
 """
 from __future__ import annotations
 
@@ -30,6 +36,7 @@ from .executor import StreamExecutor
 from .metrics import ServeMetrics, segment_summary
 from .replanner import Replanner
 from .streams import StreamSpec
+from .tracing import SpanRecorder
 
 
 @dataclasses.dataclass
@@ -56,6 +63,7 @@ class MultiStreamServer:
         batching: BatchConfig | None = None,
         engine_params: list[list] | None = None,
     ):
+        self.tracer = SpanRecorder()
         self.executor = StreamExecutor(
             models,
             plan,
@@ -68,6 +76,7 @@ class MultiStreamServer:
             jit_segments=jit_segments,
             batching=batching,
             engine_params=engine_params,
+            tracer=self.tracer,
         )
         self.replanner = replanner
         self.metrics = ServeMetrics(
@@ -131,6 +140,16 @@ class MultiStreamServer:
         ex = self.executor
         si = self._least_loaded_stream(target) if isinstance(target, int) else ex._stream_index(target)
         spec = ex.streams[si]
+        with self.tracer.span("serve.offer", stream=spec.name) as sp:
+            decision = self._admit(si, spec, frame)
+            if self.tracer.enabled:
+                sp.note(decision=decision,
+                        frames="" if decision == DROP else f"{spec.name}/{ex._frame_ids[si] - 1}")
+        return decision
+
+    def _admit(self, si: int, spec: StreamSpec, frame: Any) -> str:
+        """The admission decision for one offered frame, and its submit."""
+        ex = self.executor
         self.metrics.record_arrival(spec.name)
         decision, level = ADMIT, 0
         if self.admission is not None:
@@ -166,8 +185,10 @@ class MultiStreamServer:
     def tick(self):
         """One executor tick + metrics fold — the open-loop driver's unit
         of service (it never blocks on admission the way ``pump`` does)."""
-        self.executor.tick()
-        self._fold_completions()
+        with self.tracer.span("serve.tick"):
+            self.executor.tick()
+        with self.tracer.span("serve.fold"):
+            self._fold_completions()
 
     def finish(self):
         """Fold any unrecorded completions/ticks (end-of-run bookkeeping)."""
@@ -177,9 +198,11 @@ class MultiStreamServer:
         """Start a fresh measurement window: discard recorded metrics and
         the wall clock, keep the executor's compiled/warmed state and plan.
         The warm-then-measure idiom for benches — warmup frames (compiles,
-        cache fills) should not pollute goodput-under-SLO numbers."""
+        cache fills) should not pollute goodput-under-SLO numbers. The
+        recorder's spans and counters start afresh too."""
         ex = self.executor
         self._fold_completions()  # drop anything pending into the old window
+        self.tracer.reset()
         self._recorded = len(ex.completions)
         self._recorded_ticks = len(ex.tick_stats)
         self.metrics = ServeMetrics(
@@ -213,7 +236,8 @@ class MultiStreamServer:
     def _fold_completions(self):
         for c in self.executor.completions[self._recorded :]:
             self.metrics.record(
-                c.stream, c.latency_s, degrade=c.degrade, batch=c.batch, held=c.held
+                c.stream, c.latency_s, degrade=c.degrade, batch=c.batch, held=c.held,
+                queue_wait_s=c.queue_wait_s,
             )
         self._recorded = len(self.executor.completions)
         for t in self.executor.tick_stats[self._recorded_ticks :]:
@@ -230,4 +254,6 @@ class MultiStreamServer:
         if self.replanner is not None:
             rep["replan"] = self.replanner.summary()
             rep["segments"] = segment_summary(self.executor.segment_obs)
+        if self.tracer.enabled:
+            rep["spans"] = self.tracer.summary()
         return rep

@@ -514,15 +514,28 @@ def test_engine_wait_summary_fractions():
 
 
 def test_executor_reports_engine_wait_breakdown():
+    """With the span recorder on, every tick's per-engine wait is read off
+    its dispatch, place and block spans: non-negative, and within the
+    tick's wall time. With it off there is none."""
     ex, sm, streams = _toy_executor(n_streams=2, max_batch=2)
-    for i in range(2):
-        assert ex.submit(i, jnp.ones((1, 8)))
-    ex.run_until_drained()
+    ex.tracer.enable()
+    try:
+        for i in range(2):
+            assert ex.submit(i, jnp.ones((1, 8)))
+        ex.run_until_drained()
+    finally:
+        ex.tracer.disable()
     waited = [t for t in ex.tick_stats if t.engine_wait]
     assert waited, "no per-engine wait breakdown on any tick"
+    assert {name for t in waited for name in t.engine_wait} == {"E0", "E1"}
     for t in waited:
         for name, w in t.engine_wait.items():
             assert len(w) == 3 and all(x >= 0.0 for x in w)
+        assert sum(x for w in t.engine_wait.values() for x in w) <= t.wall_s
+    n = len(ex.tick_stats)
+    assert ex.submit(0, jnp.ones((1, 8)))
+    ex.run_until_drained()
+    assert all(t.engine_wait is None for t in ex.tick_stats[n:])
 
 
 # ---- replanner batch trigger ------------------------------------------------
