@@ -29,7 +29,8 @@ steady-state cycle in two phases:
     ``jit_segments=False`` for the bit-exact-vs-``run_all`` baseline.
   * **resolve** — frames whose route finished are completed: the host
     blocks on the finalized outputs (the only synchronization point of
-    the tick), slices merged groups apart, and stamps latencies.
+    the tick), stamps latencies, and splits merged or padded flights
+    into their members' outputs with one compiled call (``split_flight``).
 
 **Plan hot-swap** (the online re-planning runtime): ``swap_plan(new_ir)``
 replaces the active plan at a frame boundary — between ticks, or at the
@@ -66,7 +67,9 @@ enabled: ``executor.advance`` (in-flight segments, deepest first),
 ``executor.admit`` per model with child ``executor.stage_in`` (frame
 upload, donation copy, concatenation, padding), ``executor.dispatch`` per
 segment call with child ``executor.place``, ``executor.resolve`` per
-finished flight with child ``executor.block``, and ``executor.on_tick``.
+finished flight with children ``executor.block`` and, for a flight of
+several members or with pad lanes, ``executor.split``, and
+``executor.on_tick``.
 ``TickStats.engine_wait`` is a view over the tick's ``dispatch``,
 ``place`` and ``block`` spans, present only while the recorder is on.
 Every completion carries its frame's submit and admission stamps, so its
@@ -81,11 +84,13 @@ batch-independent models — see ``Pix2PixConfig(norm="instance")``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..core.pipeline import StagedModel, TickLog
 from ..core.plan_ir import PlanIR, PlanSegment, ir_from_routes
@@ -186,6 +191,20 @@ def _leading(state) -> int:
         return 1
     shape = jnp.shape(leaves[0]) if not hasattr(leaves[0], "shape") else leaves[0].shape
     return int(shape[0]) if shape else 1
+
+
+@functools.partial(jax.jit, static_argnames="sizes")
+def split_flight(out, sizes: tuple[int, ...]) -> tuple:
+    """A finished flight's output split into its members' outputs, in one
+    executable: member ``i`` gets every leaf sliced on axis 0 at ``[o, o +
+    sizes[i])``, ``o`` the sizes before it, with static bounds. Lanes past
+    ``sum(sizes)`` (a padded bucket's zero lanes) are in no member's slice.
+    jit compiles once per (output shapes, ``sizes``)."""
+    parts, o = [], 0
+    for n in sizes:
+        parts.append(jax.tree.map(lambda a: lax.slice_in_dim(a, o, o + n, axis=0), out))
+        o += n
+    return tuple(parts)
 
 
 def _as_plan_ir(plan, engine_names=None) -> PlanIR:
@@ -582,6 +601,10 @@ class StreamExecutor:
             self._block(flight.state, engine=eng)
 
     def _complete(self, flight: Flight):
+        """Block on a finished flight's output, stamp its completion time,
+        and hand each member its output: a single unpadded flight's output
+        as it is, any other flight's through one ``split_flight`` call over
+        ``[0, valid)``, which leaves the pad lanes out."""
         model = self.models[flight.model_index]
         last_eng = flight.route[-1].engine if flight.route else None
         out = self._block(model.finalize(flight.state), engine=last_eng)
@@ -598,15 +621,13 @@ class StreamExecutor:
         if len(flight.members) == 1 and not (flight.bucket and flight.bucket > valid):
             sliced = [out]
         else:
-            # padded lanes (bucket > valid) fall off here: member slices
-            # only ever index [0, valid), so the zero-filled pad rows are
-            # never observable in any completion — bit-exactness vs
-            # per-frame execution is a slicing invariant, not a masking op
-            off, sliced = 0, []
-            for m in flight.members:
-                o = off
-                sliced.append(jax.tree.map(lambda a, o=o, n=m.size: a[o : o + n], out))
-                off += m.size
+            # padded lanes (bucket > valid) fall off here: the split covers
+            # only [0, valid), so the zero-filled pad rows are never
+            # observable in any completion — bit-exactness vs per-frame
+            # execution is a slicing invariant, not a masking op
+            with self.tracer.span("executor.split", flight=flight.uid,
+                                  members=len(flight.members), bucket=flight.bucket or valid):
+                sliced = split_flight(out, tuple(m.size for m in flight.members))
         for m, o in zip(flight.members, sliced):
             name = self.streams[m.stream_index].name
             self.outputs[name].append(o)

@@ -50,6 +50,7 @@ from repro.serve import (
     metrics_from_payload,
     run_open_loop,
 )
+from repro.serve.executor import Flight, FlightMember, split_flight
 from repro.serve.metrics import ServeMetrics, TickStats, engine_wait_summary
 from repro.serve.replanner import Replanner
 
@@ -135,6 +136,104 @@ def test_coalesced_bucket_padded_execution_bit_exact():
     # ride one padded bucket-4 flight with 3 valid lanes
     assert ex.completions[0].batch == 3
     assert all(c.batch == 3 for c in ex.completions)
+
+
+def _split_spans(ex):
+    return [s for s in ex.tracer.since(0) if s.name == "executor.split"]
+
+
+def test_split_span_once_per_multi_member_or_padded_flight():
+    """With the recorder on, a finished flight is split by one compiled
+    call under ``executor.resolve`` exactly when it has several members or
+    pad lanes: a coalesced 3-member bucket-4 flight and a lone padded
+    flight each record one ``executor.split``; a single unpadded flight
+    records none and hands its output over as it is."""
+    ex, sm, streams = _toy_executor(n_streams=3, max_batch=4)
+    ex.tracer.enable()
+    try:
+        for i in range(3):
+            assert ex.submit(i, jnp.full((1, 8), float(i)))
+        ex.run_until_drained()
+        (split,) = _split_spans(ex)
+        assert split.attrs["members"] == 3 and split.attrs["bucket"] == 4
+        (resolve,) = [s for s in ex.tracer.since(0) if s.name == "executor.resolve"]
+        assert split.parent == resolve.id
+
+        # a lone frame in a padded bucket: lane 1 is padding
+        ex.tracer.reset()
+        frame = jax.random.normal(jax.random.key(3), (1, 8))
+        state = {"x": jnp.concatenate([frame, jnp.zeros((1, 8))], axis=0)}
+        member = FlightMember(0, 99, 1, time.perf_counter(), ex.tick_count)
+        ex._complete(Flight(0, [member], sm.run_segment(state, 0, sm.n_layers), 0, (), 0,
+                            valid=1, bucket=2))
+        (split,) = _split_spans(ex)
+        assert split.attrs["members"] == 1 and split.attrs["bucket"] == 2
+        np.testing.assert_array_equal(np.asarray(ex.completions[-1].output),
+                                      np.asarray(sm.run_all(frame)))
+
+        # a single unpadded flight: no split, the finalized output itself
+        ex.tracer.reset()
+        assert ex.submit(0, frame)
+        ex.run_until_drained()
+        assert _split_spans(ex) == []
+        assert ex.completions[-1].batch == 1
+        np.testing.assert_array_equal(np.asarray(ex.completions[-1].output),
+                                      np.asarray(sm.run_all(frame)))
+    finally:
+        ex.tracer.disable()
+
+
+def test_split_every_leaf_of_a_dict_output_bit_exact():
+    """A merged model whose ``finalize`` returns several leaves of
+    different shapes (as YOLOv8n's p3/p4/p5): the split slices every leaf,
+    and each member's output is bit-identical to ``run_all`` of its frame."""
+    ops = [("mul0", lambda p, s: {"x": s["x"] * 1.5 + 0.5}),
+           ("mul1", lambda p, s: {"x": s["x"] * 0.75 - 0.25})]
+    graph = LayerGraph("heads", [pointwise_meta(i, f"mul{i}", "act", (1, 8)) for i in range(2)]
+                       ).renumber()
+    sm = StagedModel(
+        name="heads",
+        ops=ops,
+        params=None,
+        graph=graph,
+        init_state=lambda x: {"x": x},
+        finalize=lambda s: {"p3": s["x"] * 2.0,
+                            "p4": s["x"].reshape(s["x"].shape[0], 4, 2),
+                            "p5": s["x"][:, :2]},
+        batch_independent=True,
+    )
+    streams = [StreamSpec(f"s{i}", 0) for i in range(3)]
+    ex = StreamExecutor([sm], make_plan_ir(("heads",), ("E0", "E1"), [[(0, 0, 1), (1, 1, 2)]]),
+                        streams, max_queue=8, merge_batches=True,
+                        batching=BatchConfig(max_batch=4), jit_segments=False)
+    frames = [jax.random.normal(jax.random.key(20 + i), (1, 8)) for i in range(3)]
+    for i, f in enumerate(frames):
+        assert ex.submit(i, f)
+    ex.run_until_drained()
+    assert [c.batch for c in ex.completions] == [3, 3, 3]
+    for s, f in zip(streams, frames):
+        (out,) = ex.outputs[s.name]
+        want = sm.run_all(f)
+        assert set(out) == set(want) == {"p3", "p4", "p5"}
+        for k in want:
+            assert out[k].shape == want[k].shape
+            np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(want[k]))
+
+
+def test_split_compiles_once_per_bucket_and_sizes():
+    """Repeated flights of one (bucket, valid) shape reuse one split
+    executable: ``split_flight``'s jit cache does not grow after the
+    first flight."""
+    ex, sm, streams = _toy_executor(n_streams=3, max_batch=4)
+    split_flight.clear_cache()
+    sizes = []
+    for rnd in range(4):
+        for i in range(3):
+            assert ex.submit(i, jax.random.normal(jax.random.key(10 * rnd + i), (1, 8)))
+        ex.run_until_drained()
+        sizes.append(split_flight._cache_size())
+    assert all(c.batch == 3 for c in ex.completions)
+    assert sizes == [1, 1, 1, 1]
 
 
 def test_coalescer_random_interleavings_bit_exact_seeded():
