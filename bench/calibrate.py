@@ -41,7 +41,7 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
 
-    from bench import check, harness, inputs, spec as spec_mod
+    from bench import archs, check, harness, spec as spec_mod
     from repro.launch import compile_cache
 
     if jax.devices()[0].platform != "tpu":
@@ -53,11 +53,11 @@ def main(argv=None) -> int:
     cell = harness.Cell(cfg)
     for seed in (int(s) for s in args.seeds.split(",")):
         rec = harness.measure(cell, spec, seed, args.seconds, release=False)
-        frames = inputs.pool(seed, cell.models[0]["img_size"])
+        pools = archs.pools(cell.models, seed)
         sched = rec["arrivals"]
-        check.control_outputs(cell.models, cell.weights, frames, sched, cell.model_of,
+        check.control_outputs(cell.models, cell.weights, pools, sched, cell.model_of,
                               jnp.dtype(cfg["precision"]["control"]))
-        control = check.compare(cfg, cell.models, cell.weights, frames, sched, cell.model_of)
+        control = check.compare(cfg, cell.models, cell.weights, pools, sched, cell.model_of)
         print(json.dumps({
             "workload": args.workload, "seed": seed, "due": len(sched),
             "dropped": sum(a.decision == "drop" for a in sched),

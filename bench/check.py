@@ -32,7 +32,7 @@ import math
 
 import numpy as np
 
-from . import reference
+from . import archs
 
 BLOCK = 8  # frames per reference call
 SPREAD_RUNS = 4  # reference runs per frame, each on the frame nudged another way
@@ -40,18 +40,30 @@ NUDGE = 1e-6  # relative size of a nudge: it changes no answer, only which value
 _JITTED: dict = {}  # one compiled reference per (model, dtype, precision)
 
 
-def nudged(frame: np.ndarray, k: int) -> np.ndarray:
-    """``frame`` times ``1 + NUDGE * z`` with ``z`` standard normal, drawn from ``k``."""
-    z = np.random.default_rng(np.random.SeedSequence([k, 5])).standard_normal(frame.shape)
-    return (frame * (1.0 + NUDGE * z)).astype(frame.dtype)
+def nudged(request, k: int):
+    """``request`` with each floating leaf times ``1 + NUDGE * z``, ``z``
+    standard normal, drawn from ``k`` leaf after leaf; other leaves (ids,
+    boxes) as they are."""
+    import jax
+
+    rng = np.random.default_rng(np.random.SeedSequence([k, 5]))
+
+    def nudge(x):
+        x = np.asarray(x)
+        if not np.issubdtype(x.dtype, np.floating):
+            return x
+        return (x * (1.0 + NUDGE * rng.standard_normal(x.shape))).astype(x.dtype)
+
+    return jax.tree.map(nudge, request)
 
 
-def reference_outputs(models, weights, frames, wanted: dict, dtype, operands=None,
+def reference_outputs(models, weights, pools, wanted: dict, dtype, operands=None,
                       precision: str = "highest", nudge: int | None = None) -> dict:
     """``{(model, pool index): output}`` of the reference, computed in
     ``dtype`` with conv operands rounded to ``operands`` (None: not
-    rounded) under matmul ``precision``, in blocks of ``BLOCK`` frames;
-    with ``nudge`` on each frame nudged by the ``nudge``-th draw."""
+    rounded) under matmul ``precision``, in blocks of ``BLOCK`` requests
+    of the model's pool (``pools[model]``) concatenated leaf by leaf;
+    with ``nudge`` on each request nudged by the ``nudge``-th draw."""
     import jax
     import jax.numpy as jnp
 
@@ -59,13 +71,14 @@ def reference_outputs(models, weights, frames, wanted: dict, dtype, operands=Non
     for mi, ks in wanted.items():
         if not ks:
             continue
-        cfg, fwd = models[mi], reference.FORWARD[models[mi]["arch"]]
+        cfg, fwd = models[mi], archs.load(models[mi]["arch"]).forward
         w = jax.tree.map(lambda a: a.astype(dtype), weights[mi])
         key = (json.dumps(cfg, sort_keys=True), jnp.dtype(dtype).name, str(operands), precision)
         if key not in _JITTED:
 
             def f(p, x, cfg=cfg, fwd=fwd):
-                y = fwd(p, x.astype(dtype), cfg, operands)
+                x = jax.tree.map(lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a, x)
+                y = fwd(p, x, cfg, operands)
                 return jax.tree.map(lambda a: a.astype(jnp.float32), y)
 
             _JITTED[key] = jax.jit(f)
@@ -75,7 +88,8 @@ def reference_outputs(models, weights, frames, wanted: dict, dtype, operands=Non
             for b in range(0, len(ks), BLOCK):
                 blk = ks[b:b + BLOCK]
                 pad = blk + [blk[0]] * (BLOCK - len(blk))  # one compiled shape
-                x = np.concatenate([frames[k] if nudge is None else nudged(frames[k], nudge) for k in pad])
+                reqs = [pools[mi][k] if nudge is None else nudged(pools[mi][k], nudge) for k in pad]
+                x = jax.tree.map(lambda *leaves: np.concatenate(leaves), *reqs)
                 y = jax.device_get(f(w, x))
                 for j, k in enumerate(blk):
                     out[mi, k] = jax.tree.map(lambda a, j=j: a[j:j + 1], y)
@@ -109,7 +123,7 @@ def _mean(outs):
     return jax.tree.map(lambda *a: np.mean(np.stack([np.asarray(x, np.float64) for x in a]), 0), *outs)
 
 
-def compare(config, models, weights, frames, sched, model_of) -> dict:
+def compare(config, models, weights, pools, sched, model_of) -> dict:
     """``{"<model>.gap_over_spread": {"value", "limit", "frames", "gap",
     "spread"}}`` for every model; ``gap`` and ``spread`` are the two root
     mean squares whose ratio is the value."""
@@ -117,7 +131,7 @@ def compare(config, models, weights, frames, sched, model_of) -> dict:
 
     prec = config["precision"]
     wanted = {mi: {a.pool for a in sampled(sched, model_of, mi)} for mi in range(len(models))}
-    runs = [reference_outputs(models, weights, frames, wanted, jnp.dtype(prec["dtype"]),
+    runs = [reference_outputs(models, weights, pools, wanted, jnp.dtype(prec["dtype"]),
                               jnp.dtype(prec["conv_operands"]), nudge=k) for k in range(SPREAD_RUNS)]
     checks = {}
     for mi, m in enumerate(models):
@@ -135,11 +149,11 @@ def compare(config, models, weights, frames, sched, model_of) -> dict:
     return checks
 
 
-def control_outputs(models, weights, frames, sched, model_of, dtype) -> None:
+def control_outputs(models, weights, pools, sched, model_of, dtype) -> None:
     """Put the reference computed in ``dtype`` in the program's place: every
     sampled arrival's output becomes the lower-precision reference's."""
     wanted = {mi: {a.pool for a in sampled(sched, model_of, mi)} for mi in range(len(models))}
-    refs = reference_outputs(models, weights, frames, wanted, dtype, precision="default")
+    refs = reference_outputs(models, weights, pools, wanted, dtype, precision="default")
     for mi in range(len(models)):
         for a in sampled(sched, model_of, mi):
             a.output = refs[mi, a.pool]

@@ -3,8 +3,9 @@
 The system under test is the serving stack as users build it,
 ``repro.serve.build_server`` with the configuration file's ``build``
 arguments. The benchmark makes the weights (``reference.make_weights``)
-and the frames (``inputs.pool``) from the seed and hands them to it; the
-window drives ``server.offer`` and ``server.tick`` itself:
+and one pool of requests per model (``archs.pools``) from the seed and
+hands them to it; the window drives ``server.offer`` and ``server.tick``
+itself:
 
 * every frame due by now is offered before the next tick, and the offer's
   time is recorded, so latency runs from the due time (the offer's
@@ -14,6 +15,10 @@ window drives ``server.offer`` and ``server.tick`` itself:
 * outputs of a seeded sample of the window's arrivals are kept, all others
   are released as they complete, and the sample is compared with the
   reference once the program's state is freed (``check.py``).
+
+In ``--trace 1`` runs the profiler and the program's span recorders are on
+for a tail of further arrivals after the window, and the run record keeps
+what they show (``trace.py``, ``spans.py``).
 """
 from __future__ import annotations
 
@@ -23,7 +28,7 @@ import shutil
 import tempfile
 import time
 
-from . import arrivals, check, flops, inputs, reference
+from . import archs, arrivals, check, flops, inputs, reference
 
 SAMPLE_PER_MODEL = 96  # arrivals per model compared with the reference
 DRAIN_S = 30.0  # longest the window's admitted frames may take to complete
@@ -67,21 +72,27 @@ class Cell:
     bookkeeping of every offer it makes."""
 
     def __init__(self, config: dict, overrides: dict | None = None):
-        """``overrides`` (``img_size``, ``base``) shrink the models for the
-        benchmark's own tests on the CPU."""
+        """``overrides`` shrink the models for the benchmark's own tests on
+        the CPU: each applies to every model and to the ``build`` arguments
+        that have its key."""
+        from repro.core.pipeline import executable_label
         from repro.serve import BatchConfig, build_server
 
         self.config = config
         self.models = _model_cfgs(config, overrides)
         kw = dict(config["build"])
-        if overrides:
-            kw.update(img=overrides.get("img_size", kw["img"]), base=overrides.get("base", kw["base"]))
+        kw.update({k: v for k, v in (overrides or {}).items() if k in kw})
         if kw.get("batching"):
             kw["batching"] = BatchConfig(**kw["batching"])
         self.deadline_s = kw["deadline_ms"] / 1e3
         self.bundle = build_server(**kw)
         self.server = self.bundle.server
-        self.executors = [s.executor for s in getattr(self.server, "servers", [self.server])]
+        replicas = getattr(self.server, "servers", [self.server])
+        self.executors = [s.executor for s in replicas]
+        self.tracers = [s.tracer for s in replicas]
+        # each model's segment executables compile as jit_<label>.<lo>_<hi>.<impl>
+        self.executables = {c["name"]: f"jit_{executable_label(m.name)}."
+                            for c, m in zip(self.models, self.bundle.models)}
         self.streams = [s.name for s in self.bundle.streams]
         self.model_of = {s.name: s.model_index for s in self.bundle.streams}
         self.flops = {s.name: flops.per_frame(self.models[s.model_index]) for s in self.bundle.streams}
@@ -141,16 +152,18 @@ class Cell:
 
     # -- warm-up ----------------------------------------------------------------
 
-    def warm_up(self, frame, rounds: int = 2) -> None:
+    def warm_up(self, pools, rounds: int = 2) -> None:
         """Compile and run every shape the window can use, through the
-        public entry: for each replica's streams of each model, flights of
-        every size the coalescer can form (one, for models it does not
-        batch), then the degraded single-segment route that admission
-        sheds to under pressure. The second round runs warm, so the
-        coalescer's service-time estimates start from steady values."""
+        public entry, each model offered the first request of its pool:
+        for each replica's streams of each model, flights of every size the
+        coalescer can form (one, for models it does not batch), then the
+        degraded single-segment route that admission sheds to under
+        pressure. The second round runs warm, so the coalescer's
+        service-time estimates start from steady values."""
         bc = self.executors[0].batching
+        first = [p[0] for p in pools]
         for s in self.streams:  # fixes each stream's replica
-            self.offer(s, frame)
+            self.offer(s, first[self.model_of[s]])
         self.drain()
         router = getattr(self.server, "router", None)
         groups: dict = {}
@@ -163,23 +176,26 @@ class Cell:
                     merges = bc.enabled and self.executors[0].merge_batches[mi]
                     for k in range(1, (bc.max_batch if merges else 1) + 1):
                         for j in range(k):
-                            self.offer(mine[j % len(mine)], frame)
+                            self.offer(mine[j % len(mine)], first[mi])
                         self.drain()
                     for j in range(64 * len(mine)):
-                        if self.offer(mine[j % len(mine)], frame) in ("shed_route", "drop"):
+                        if self.offer(mine[j % len(mine)], first[mi]) in ("shed_route", "drop"):
                             break
                     self.drain()
 
     # -- the window ---------------------------------------------------------------
 
-    def window(self, schedule, frames, seconds: float, trace_dir: str | None = None) -> dict:
-        """Offer ``schedule`` open loop and tick until every admitted frame
-        has completed; returns timings.
+    def window(self, schedule, pools, seconds: float, trace_dir: str | None = None) -> dict:
+        """Offer ``schedule`` open loop, each arrival the request of its
+        stream's model's pool, and tick until every admitted frame has
+        completed; returns timings.
 
         With ``trace_dir`` the profiler records ``[seconds, seconds +
-        TRACE_S)``, a tail of further arrivals after the window: neither
-        the profiler's start nor its stop stalls the window, and what
-        tracing costs the host stays out of the window's numbers."""
+        TRACE_S)``, a tail of further arrivals after the window, with the
+        program's span recorders on: neither the profiler's start nor its
+        stop stalls the window, and what tracing and the recorders cost the
+        host stays out of the window's numbers. The recorders' counters
+        over the tail are returned as ``span_counters``."""
         import jax
 
         server, clock = self.server, time.perf_counter
@@ -189,6 +205,7 @@ class Cell:
         n_window = sum(a.t_due < seconds for a in schedule)
         trace_at = (seconds, seconds + TRACE_S) if trace_dir else None
         traced = None  # [start, end] of the traced tail, seconds after t0
+        counters = None
         spans = lambda name: SPANS_OFF  # noqa: E731
         i, n, backlog = 0, len(schedule), None
         pauses: list = []
@@ -206,9 +223,15 @@ class Cell:
                     opts.host_tracer_level, opts.python_tracer_level = 1, 0
                     opts.enable_hlo_proto = False
                     jax.profiler.start_trace(trace_dir, profiler_options=opts)
+                    for tr in self.tracers:
+                        tr.enable()
+                        tr.reset()  # counters of the tail alone, also where it was on before
                     traced = [clock() - t0, None]
                     spans = jax.profiler.TraceAnnotation
                 elif traced and traced[1] is None and now >= trace_at[1]:
+                    counters = merge_counters([tr.summary()["counters"] for tr in self.tracers])
+                    for tr in self.tracers:
+                        tr.disable()
                     jax.profiler.stop_trace()
                     traced[1] = clock() - t0
                     spans = lambda name: SPANS_OFF  # noqa: E731
@@ -217,7 +240,7 @@ class Cell:
                         while i < n and schedule[i].t_due <= clock() - t0:
                             a = schedule[i]
                             a.t_offer = clock() - t0
-                            self.offer(a.stream, frames[a.pool], a)
+                            self.offer(a.stream, pools[self.model_of[a.stream]][a.pool], a)
                             i += 1
                             if i == n_window:
                                 backlog = server.executor.pending
@@ -249,6 +272,7 @@ class Cell:
             "end_s": end,
             "report": report or server.report(),
             "traced_s": traced,
+            "span_counters": counters,
             "backlog_at_horizon": backlog if backlog is not None else 0,
             "gc_pauses": pauses,
             "stalls": stalls,
@@ -268,8 +292,22 @@ class Cell:
     def close(self) -> None:
         """Release the program's state; the weights stay for the reference."""
         self.bundle.close()
-        self.bundle = self.server = self.executors = None
+        self.bundle = self.server = self.executors = self.tracers = None
         gc.collect()
+
+
+def merge_counters(summaries: list[dict]) -> dict:
+    """One replica's span counters (``SpanRecorder.summary()["counters"]``)
+    from several: counts and seconds summed, the longest kept."""
+    out: dict = {}
+    for counters in summaries:
+        for name, c in counters.items():
+            m = out.setdefault(name, {"count": 0, "total_s": 0.0, "self_s": 0.0, "max_s": 0.0})
+            m["count"] += c["count"]
+            m["total_s"] += c["total_s"]
+            m["self_s"] += c["self_s"]
+            m["max_s"] = max(m["max_s"], c["max_s"])
+    return out
 
 
 def memory_peak(chips: int) -> int | None:
@@ -297,10 +335,10 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_start: float) -> d
 def measure(cell: Cell, spec: dict, seed: int, seconds: float, trace: bool = False,
             t_start: float | None = None, cs0: dict | None = None, after_window=None,
             release: bool = True) -> dict:
-    """Weights, frames and arrivals from the seed, warm-up, the window and
-    the comparison, on a built ``cell``.
+    """Weights, request pools and arrivals from the seed, warm-up, the
+    window and the comparison, on a built ``cell``.
 
-    ``after_window`` (called with the cell, the arrivals and the frames)
+    ``after_window`` (called with the cell, the arrivals and the pools)
     and ``release=False`` serve the benchmark's own tests, which run it on
     the CPU at a small size with the timed path broken underneath."""
     import jax
@@ -311,42 +349,44 @@ def measure(cell: Cell, spec: dict, seed: int, seconds: float, trace: bool = Fal
     stamps = [("built", time.perf_counter())]
     cell.load_weights(seed)
     stamps.append(("weights", time.perf_counter()))
-    frames = inputs.pool(seed, cell.models[0]["img_size"])
-    sched = arrivals.schedule(spec["mix"], cell.streams, seed, seconds, len(frames))
+    pools = archs.pools(cell.models, seed)
+    sched = arrivals.schedule(spec["mix"], cell.streams, seed, seconds, inputs.POOL)
     arrivals.mark_sample(sched, cell.model_of, SAMPLE_PER_MODEL, seed)
     tail = []
     if trace:
-        tail = arrivals.schedule(spec["mix"], cell.streams, seed, TRACE_S, len(frames), salt=1)
+        tail = arrivals.schedule(spec["mix"], cell.streams, seed, TRACE_S, inputs.POOL, salt=1)
         for a in tail:
             a.t_due += seconds
     stamps.append(("inputs", time.perf_counter()))
-    cell.warm_up(frames[0])
+    cell.warm_up(pools)
     stamps.append(("warm_up", time.perf_counter()))
     cs1 = compile_cache.stats()
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     t_setup = time.perf_counter()
     try:
-        timing = cell.window(sched + tail, frames, seconds, trace_dir)
+        timing = cell.window(sched + tail, pools, seconds, trace_dir)
         cs2 = compile_cache.stats()
         report = timing["report"]
         peak = memory_peak(spec["cell"]["chips"])
         if after_window is not None:
-            after_window(cell, sched, frames)
+            after_window(cell, sched, pools)
         outputs = {id(a): jax.device_get(a.output) for a in sched if a.output is not None}
         for a in sched:
             a.output = outputs.get(id(a))
         if release:
             cell.close()
-        reduced = None
+        reduced = program = None
         if trace_dir:
+            from . import spans
             from . import trace as trace_mod
 
             reduced = trace_mod.reduce(trace_mod.load(trace_dir))
+            program = dict(spans.reduce(spans.load(trace_dir)), counters=timing["span_counters"])
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
     t_check = time.perf_counter()
-    checks = check.compare(spec["config"], cell.models, cell.weights, frames, sched, cell.model_of)
+    checks = check.compare(spec["config"], cell.models, cell.weights, pools, sched, cell.model_of)
     t_checked = time.perf_counter()
     return {
         "seconds": seconds,
@@ -355,9 +395,13 @@ def measure(cell: Cell, spec: dict, seed: int, seconds: float, trace: bool = Fal
         "arrivals": sched,
         "tail": tail,
         "flops": cell.flops,
+        "models": cell.models,
+        "model_of": cell.model_of,
+        "executables": cell.executables,
         "ticks": timing["ticks"],
         "report": report,
         "trace": reduced,
+        "spans": program,
         "traced_s": timing["traced_s"],
         "backlog_at_horizon": timing["backlog_at_horizon"],
         "gc_pauses": timing["gc_pauses"],

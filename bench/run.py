@@ -93,6 +93,8 @@ def summary(rec: dict) -> dict:
         "setup_phases_s": rec["setup_phases_s"], "check_s": rec["check_s"],
         "trace": None if rec["trace"] is None else {
             k: rec["trace"][k] for k in ("window_s", "busy_s", "idle_share", "idle_by_span_s")},
+        "spans": None if rec.get("spans") is None else {
+            k: rec["spans"][k] for k in ("idle_host_busy_pct", "idle_by_span")},
     }
 
 
@@ -133,9 +135,9 @@ def main(argv=None) -> int:
     from repro.launch import compile_cache
 
     compile_cache.enable()
-    peak = peaks(devices[0].device_kind)["bf16_flops_per_s"]
+    row = peaks(devices[0].device_kind)
     rec = harness.run(spec, args.seed, args.seconds, bool(args.trace), T_START)
-    rec["peak"] = peak
+    rec["peaks"], rec["peak"] = row, row["bf16_flops_per_s"]
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind, "count": chips,
               "memory_peak_bytes": rec["memory_peak_bytes"]}
     if args.trace:

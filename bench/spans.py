@@ -18,6 +18,9 @@ compiles as ``jit_<model>.<lo>_<hi>.<impl>``) and ``scope`` its op's
 * ``idle_host_busy_pct``: time the chip is idle while the host is inside
   ``serve.tick`` and outside ``executor.block``, over the window;
 * ``module_time``: device time per module, beside the busy union;
+* ``op_time``: device time of every operation, by module and op;
+* ``reduce``: the four above, as a traced run's record keeps them
+  (``harness.measure``, ``rec["spans"]``);
 * ``owner``: the deepest span covering most of an interval, such as a host
   stall.
 
@@ -196,6 +199,32 @@ def module_time(trace: dict) -> dict:
         busy = sum(e - s for s, e in _busy(ops, w0, w1)) / 1e9
         out[plane] = {"busy_s": busy, "modules": dict(sorted(per.items(), key=lambda kv: -kv[1]))}
     return out
+
+
+def op_time(trace: dict) -> dict:
+    """``{module: {op: seconds}}``: the device time of every operation in
+    the window, summed over planes, each op named as ``trace.op_name``
+    names it (``%fusion.1 f32[1,258,258,3]``)."""
+    from .trace import op_name
+
+    w0, w1 = window(trace)
+    out: dict = {}
+    for ops in trace["ops"].values():
+        for name, s, d, mod, _ in ops:
+            t = min(float(s) + float(d), w1) - max(float(s), w0)
+            if t > 0:
+                per = out.setdefault(mod or "none", {})
+                key = op_name(name)
+                per[key] = per.get(key, 0.0) + t / 1e9
+    return out
+
+
+def reduce(trace: dict) -> dict:
+    """What a traced run's record keeps of the program's spans and the
+    device's operations over the window: ``idle_by_span``,
+    ``idle_host_busy_pct``, ``module_time`` and ``op_time``."""
+    return {"idle_by_span": idle_by_span(trace), "idle_host_busy_pct": idle_host_busy_pct(trace),
+            "module_time": module_time(trace), "op_time": op_time(trace)}
 
 
 def top_ops(trace: dict, n: int = 10) -> list:

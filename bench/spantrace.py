@@ -243,7 +243,8 @@ def main(argv=None) -> int:
     cs0 = compile_cache.stats()
     cell = harness.Cell(spec["config"])
     rec, shown, grabbed = trace_run(cell, spec, args.seed, args.seconds, T_START, cs0)
-    rec["peak"] = peaks(devices[0].device_kind)["bf16_flops_per_s"]
+    row = peaks(devices[0].device_kind)
+    rec["peaks"], rec["peak"] = row, row["bf16_flops_per_s"]
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind, "count": chips,
               "memory_peak_bytes": rec["memory_peak_bytes"],
               "busy_s": rec["trace"]["busy_mean_s"], "window_s": rec["trace"]["window_s"]}

@@ -31,15 +31,48 @@ def issue_ms_per_frame(rec) -> float | None:
     return 1e3 * sum(t.wall_s - t.blocked_s for t in rec["ticks"]) / n
 
 
+def tail_completed(rec, model: str | None = None) -> list:
+    """Frames (of ``model``, a configuration's model name, or of every
+    model) that completed inside the traced tail."""
+    lo, hi = rec["traced_s"]
+    mi = None if model is None else [m["name"] for m in rec["models"]].index(model)
+    return [a for a in rec["arrivals"] + rec["tail"]
+            if a.done and lo <= a.t_offer + a.latency_s <= hi and (mi is None or rec["model_of"][a.stream] == mi)]
+
+
 def busy_ms_per_frame(rec) -> float | None:
     """Device busy time in the traced tail, summed over the cell's chips,
     per frame completed inside it."""
-    tr, span = rec["trace"], rec["traced_s"]
-    if tr is None:
+    if rec["trace"] is None:
         return None
-    n = sum(1 for a in rec["arrivals"] + rec["tail"]
-            if a.done and span[0] <= a.t_offer + a.latency_s <= span[1])
-    return 1e3 * sum(tr["busy_s"]) / n if n else None
+    n = len(tail_completed(rec))
+    return 1e3 * sum(rec["trace"]["busy_s"]) / n if n else None
+
+
+def span_self_ms_per_frame(rec, name: str) -> float | None:
+    """Self time of the program's span ``name`` in the traced tail, summed
+    over replicas, per frame completed inside it."""
+    c = (rec.get("spans") or {}).get("counters", {}).get(name)
+    n = len(tail_completed(rec)) if c else 0
+    return 1e3 * c["self_s"] / n if n else None
+
+
+def model_device_ms_per_frame(rec, model: str) -> float | None:
+    """Device time of ``model``'s segment executables in the traced tail,
+    summed over chips, per frame of that model completed inside it."""
+    sp = rec.get("spans")
+    if sp is None:
+        return None
+    prefix = rec["executables"][model]
+    t = sum(s for plane in sp["module_time"].values() for mod, s in plane["modules"].items()
+            if mod.startswith(prefix))
+    n = len(tail_completed(rec, model))
+    return 1e3 * t / n if t and n else None
+
+
+def idle_host_busy_pct(rec) -> float | None:
+    sp = rec.get("spans")
+    return None if sp is None else sp["idle_host_busy_pct"]
 
 
 def idle_pct(rec) -> float | None:

@@ -34,7 +34,7 @@ def main(argv=None) -> int:
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
     import jax
 
-    from bench import arrivals, harness, inputs
+    from bench import archs, arrivals, harness, inputs
     from bench.run import read_metric
     from repro.launch import compile_cache
 
@@ -46,13 +46,13 @@ def main(argv=None) -> int:
     mix = json.loads((ROOT / "bench" / "traffic" / f"{args.traffic}.json").read_text())
     cell = harness.Cell(config)
     cell.load_weights(args.seed)
-    frames = inputs.pool(args.seed, cell.models[0]["img_size"])
-    cell.warm_up(frames[0])
+    pools = archs.pools(cell.models, args.seed)
+    cell.warm_up(pools)
     print(json.dumps({"config": args.config, "warm": compile_cache.stats()}), flush=True)
     for rate in (float(r) for r in args.rates.split(",")):
         sched = arrivals.schedule(dict(mix, aggregate_hz=rate), cell.streams, args.seed, args.seconds,
-                                  len(frames))
-        timing = cell.window(sched, frames, args.seconds)
+                                  inputs.POOL)
+        timing = cell.window(sched, pools, args.seconds)
         rec = {"arrivals": sched, "seconds": args.seconds, "deadline_s": cell.deadline_s}
         out = {m: read_metric("end_to_end", m, rec)
                for m in ("goodput_fps", "latency_p50_ms", "latency_p95_ms")}

@@ -25,7 +25,7 @@ from bench import arrivals, check, flops, harness, peaks, spec, stats, trace  # 
 from bench.run import read_metric, result, summary  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
-SMALL = {"img_size": 128, "base": 8}  # the harness tests' model size
+SMALL = {"img_size": 128, "img": 128, "base": 8}  # the harness tests' model size: models' and build's keys
 SEED = 2**31 + 23
 
 
@@ -234,10 +234,10 @@ def _half_batch(y):
     return y if n == 1 else jnp.concatenate([y[: n - n // 2], y[: n // 2]])
 
 
-def _control(cell, sched, frames):
+def _control(cell, sched, pools):
     import jax.numpy as jnp
 
-    check.control_outputs(cell.models, cell.weights, frames, sched, cell.model_of, jnp.bfloat16)
+    check.control_outputs(cell.models, cell.weights, pools, sched, cell.model_of, jnp.bfloat16)
 
 
 CASES = [
@@ -267,6 +267,39 @@ def test_correct_holds_only_for_the_sound_program(cells, workload, case, want):
     assert check.correct(rec["checks"]) is want, rec["checks"]
 
 
+@pytest.fixture(scope="module")
+def fleet():
+    """The four-replica configuration (not yet a cell: PERF.md section 7),
+    its replicas on the one CPU device, at ``SMALL`` size."""
+    config = json.loads((ROOT / "bench" / "configs" / "pix2pix_bn_yolov8n_r4.json").read_text())
+    config["precision"]["conv_operands"] = "float32"
+    config["build"]["admission"] = False
+    sp = {"cell": {"chips": 1}, "config": config, "mix": {"process": "poisson", "aggregate_hz": 200}}
+    return sp, harness.Cell(config, SMALL)
+
+
+@pytest.mark.parametrize("case,want", [("sound", True), ("altered", False)])
+def test_correct_holds_only_for_the_sound_fleet(fleet, case, want):
+    sp, cell = fleet
+    assert len(cell.executors) == len(cell.tracers) == 4 and len(cell.streams) == 20
+    restore = _wrap_finalize(cell, _altered if case == "altered" else lambda y: y)
+    try:
+        rec = harness.measure(cell, sp, SEED, 1.0, release=False)
+    finally:
+        restore()
+    assert all(c["frames"] > 0 for c in rec["checks"].values()), rec["checks"]
+    assert check.correct(rec["checks"]) is want, rec["checks"]
+
+
+def test_span_counters_of_replicas_add_up():
+    a = {"executor.dispatch": {"count": 2, "total_s": 0.5, "self_s": 0.25, "max_s": 0.3}}
+    b = {"executor.dispatch": {"count": 1, "total_s": 0.25, "self_s": 0.125, "max_s": 0.25},
+         "serve.tick": {"count": 1, "total_s": 1.0, "self_s": 0.5, "max_s": 1.0}}
+    assert harness.merge_counters([a, b]) == {
+        "executor.dispatch": {"count": 3, "total_s": 0.75, "self_s": 0.375, "max_s": 0.3},
+        "serve.tick": {"count": 1, "total_s": 1.0, "self_s": 0.5, "max_s": 1.0}}
+
+
 def test_trace_reduction_on_a_chip_trace():
     rec = json.loads((ROOT / "bench" / "testdata" / "trace_v5e_50ms.json").read_text())
     r = trace.reduce(rec)
@@ -279,3 +312,34 @@ def test_trace_reduction_on_a_chip_trace():
                                    "bench.wait": pytest.approx(0.003296869, abs=1e-9)}
     assert r["idle_gaps"][0] == ["bench.tick", pytest.approx(0.010529371, abs=1e-9)]
     assert r["device_ops"][0] == ["%fusion.1 f32[1,258,258,3]", pytest.approx(0.001700613, abs=1e-9)]
+
+
+def test_the_traced_tail_turns_the_program_recorders_on(cells, tmp_path):
+    """The window runs with the span recorders off; the tail after it with
+    them on, and the window returns their counters over the tail alone."""
+    from bench import archs, inputs, spans
+    from bench.stats import model_device_ms_per_frame
+
+    sp, cell = cells("pix2pix_bn_yolov8n.live")
+    pools = archs.pools(cell.models, SEED)
+    cell.load_weights(SEED)
+    cell.warm_up(pools)
+    mix = {"process": "poisson", "aggregate_hz": 20}
+    sched = arrivals.schedule(mix, cell.streams, SEED, 0.5, inputs.POOL)
+    tail = arrivals.schedule(mix, cell.streams, SEED, harness.TRACE_S, inputs.POOL, salt=1)
+    for a in tail:
+        a.t_due += 0.5
+    timing = cell.window(sched + tail, pools, 0.5, str(tmp_path))
+    assert "spans" not in timing["report"]  # the window's counters, recorder off
+    assert not any(tr.enabled for tr in cell.tracers)
+    counters = timing["span_counters"]
+    assert counters["serve.tick"]["count"] > 0
+    assert 0 < counters["executor.dispatch"]["self_s"] <= counters["executor.dispatch"]["total_s"]
+    # every tail frame was offered inside the traced tail, none of the window's
+    assert counters["serve.offer"]["count"] == len(tail)
+    prog = spans.load(str(tmp_path))
+    rec = {"traced_s": timing["traced_s"], "arrivals": sched, "tail": tail, "models": cell.models,
+           "model_of": cell.model_of, "executables": cell.executables,
+           "spans": dict(spans.reduce(prog), counters=counters)}
+    assert {n for n, *_ in prog["spans"]} >= {"serve.tick", "executor.dispatch", "bench.tick"}
+    assert model_device_ms_per_frame(rec, "pix2pix") > 0 and model_device_ms_per_frame(rec, "yolov8") > 0
