@@ -138,10 +138,13 @@ class DevicePool:
     (``D // R`` devices each, round-robin reuse once R exceeds D); on
     1-device hosts — CPU CI — every replica binds the virtual 2-engine
     GPU/DLA pair to the single device, so the whole fleet still runs.
-    Placement is exposed as per-engine ``place_fns`` (``jax.device_put``
-    closures) in the shape ``StreamExecutor`` consumes, and the weights as
+    Placement is exposed in the shapes ``StreamExecutor`` consumes: each
+    engine's device, where stage-in uploads frames (``engine_devices``),
+    per-engine ``place_fns`` (``jax.device_put`` closures where a route can
+    cross chips, the identity where it cannot), and the weights as
     per-engine copies made once at build time (``place_params``); on a
-    1-device pool both collapse to identity so the hot path pays nothing.
+    1-device pool all three collapse to identity so the hot path pays
+    nothing.
     """
 
     def __init__(self, engines, devices=None):
@@ -192,20 +195,30 @@ class DevicePool:
         devs = self.replica_devices(replica, n_replicas)
         return tuple(e.bound(devs[i % len(devs)]) for i, e in enumerate(self.engines))
 
-    def place_fns(self, replica: int, n_replicas: int) -> list:
-        """Per-engine state-placement closures for ``StreamExecutor``."""
+    def engine_devices(self, replica: int, n_replicas: int) -> list | None:
+        """Each engine's device in this replica's slice, where stage-in
+        uploads the frames of routes that start there; None on a 1-device
+        pool, where there is one place to upload to."""
         if len(self.devices) == 1:
-            # single-device host: device_put would be a no-op round trip
+            return None
+        return [e.device for e in self.engine_slice(replica, n_replicas)]
+
+    def place_fns(self, replica: int, n_replicas: int) -> list:
+        """Per-engine state-placement closures for ``StreamExecutor``: a
+        ``jax.device_put`` onto the engine's device where a route can cross
+        chips, the identity where it cannot. A state lives on the chip
+        stage-in uploaded it to or a segment left it on, so on a 1-device
+        pool, and for a replica whose engines all bind one chip, every
+        closure is the identity."""
+        devs = self.engine_devices(replica, n_replicas)
+        if devs is None or len(set(devs)) == 1:
             return [lambda state: state for _ in self.engines]
         import jax
 
-        fns = []
-        for e in self.engine_slice(replica, n_replicas):
-            dev = e.device
-            fns.append(
-                lambda state, dev=dev: jax.tree.map(lambda x: jax.device_put(x, dev), state)
-            )
-        return fns
+        return [
+            lambda state, dev=dev: jax.tree.map(lambda x: jax.device_put(x, dev), state)
+            for dev in devs
+        ]
 
     def place_params(self, models, replica: int, n_replicas: int) -> list | None:
         """Every model's params, copied once onto each engine's device of

@@ -65,11 +65,12 @@ Per-tick host wall/blocked time is recorded in ``tick_stats`` (see
 ``serve.tracing.SpanRecorder`` (the server's, shared), which is off unless
 enabled: ``executor.advance`` (in-flight segments, deepest first),
 ``executor.admit`` per model with child ``executor.stage_in`` (frame
-upload, donation copy, concatenation, padding), ``executor.dispatch`` per
-segment call with child ``executor.place``, ``executor.resolve`` per
-finished flight with children ``executor.block`` and, for a flight of
-several members or with pad lanes, ``executor.split``, and
-``executor.on_tick``.
+upload, straight to the device of the route's first engine where engines
+are bound to devices; donation copy; concatenation; padding),
+``executor.dispatch`` per segment call with child ``executor.place``,
+``executor.resolve`` per finished flight with children ``executor.block``
+and, for a flight of several members or with pad lanes,
+``executor.split``, and ``executor.on_tick``.
 ``TickStats.engine_wait`` is a view over the tick's ``dispatch``,
 ``place`` and ``block`` spans, present only while the recorder is on.
 Every completion carries its frame's submit and admission stamps, so its
@@ -193,6 +194,16 @@ def _leading(state) -> int:
     return int(shape[0]) if shape else 1
 
 
+def _upload(x, dev, donate: bool):
+    """One leaf of a frame's initial state on ``dev``. A host array goes
+    straight there in one upload, which makes a buffer of its own; so does
+    a device array that lives elsewhere. Only a device array already on
+    ``dev`` is copied, and only where segments donate their input state."""
+    if isinstance(x, jax.Array) and x.devices() == {dev}:
+        return jnp.copy(x) if donate else x
+    return jax.device_put(x, dev)
+
+
 @functools.partial(jax.jit, static_argnames="sizes")
 def split_flight(out, sizes: tuple[int, ...]) -> tuple:
     """A finished flight's output split into its members' outputs, in one
@@ -240,6 +251,7 @@ class StreamExecutor:
         batching: BatchConfig | None = None,
         engine_params: list[list] | None = None,
         tracer: SpanRecorder | None = None,
+        engine_devices: list | None = None,
     ):
         ir = _as_plan_ir(plan, engine_names)
         if len(models) != ir.n_models:
@@ -271,6 +283,10 @@ class StreamExecutor:
         # per-engine weight copies on the engine's device (``DevicePool.
         # place_params``); None = every engine runs on the models' params
         self.engine_params = engine_params
+        # each engine's device (``DevicePool.engine_devices``): stage-in
+        # uploads a frame straight to its route's first engine; None = one
+        # device, where frames go wherever JAX puts them by default
+        self.engine_devices = engine_devices
         self.engine_names = list(engine_names) if engine_names else list(ir.engine_names)
         self.model_labels = model_labels or [m.name for m in models]
         self.queues = [FrameQueue(max_queue) for _ in streams]
@@ -791,22 +807,28 @@ class StreamExecutor:
 
     def _stage_in(self, mi: int, picked: list, held: bool, coalesce: bool) -> list[Flight]:
         """The flights of the frames ``picked`` off model ``mi``'s queues:
-        each frame's initial state on the device (copied where segments
-        donate), clean frames concatenated and padded to their bucket
-        where the model merges, degraded frames in flights of their own."""
+        each frame's initial state on the device (with ``engine_devices``,
+        uploaded straight to its route's first engine's device; copied
+        where segments donate and the upload made no buffer of its own),
+        clean frames concatenated and padded to their bucket where the
+        model merges, degraded frames in flights of their own."""
         model = self.models[mi]
         bc = self.batching
         members, states = [], []
+        route = self.plan.route(mi)
+        rev = self.plan.revision
         for si, fid, frame, t_sub, degrade in picked:
             size = int(frame.shape[0]) if hasattr(frame, "shape") and frame.shape else 1
             members.append(FlightMember(si, fid, size, t_sub, self.tick_count, degrade=degrade))
             state = model.init_state(frame)
-            if self._donate:
+            if self.engine_devices is not None:
+                first = (self._degraded_route(mi) if degrade >= 2 else route)[0].engine
+                dev = self.engine_devices[first]
+                state = jax.tree.map(lambda x: _upload(x, dev, self._donate), state)
+            elif self._donate:
                 # segments donate their input state: never the caller's frame
                 state = jax.tree.map(jnp.copy, state)
             states.append(state)
-        route = self.plan.route(mi)
-        rev = self.plan.revision
         # Degraded frames never merge: level-1 frames have shed shapes,
         # level-2 frames run the degraded route, both incompatible with a
         # concatenated full-route group.
@@ -820,9 +842,10 @@ class StreamExecutor:
                 # pad to the compiled bucket with zero lanes; _complete
                 # slices members out of [0, total) so the pads are never
                 # observable (bit-exact vs per-frame execution)
+                dev = None if self.engine_devices is None else self.engine_devices[route[0].engine]
                 merged = jax.tree.map(
                     lambda a: jnp.concatenate(
-                        [a, jnp.zeros((bucket - total,) + a.shape[1:], a.dtype)], axis=0
+                        [a, jnp.zeros((bucket - total,) + a.shape[1:], a.dtype, device=dev)], axis=0
                     ),
                     merged,
                 )

@@ -9,10 +9,12 @@ engine slice — the slices are value-identical, only their device binding
 differs, so one solution serves every replica and the jit caches on the
 shared models mean one compilation fleet-wide). A ``DevicePool``
 (``core.engine``) supplies each replica's engine slice, the
-``jax.device_put`` placement closures its executor applies per segment,
-and the weights, copied once at build time onto each engine's device;
-on 1-device hosts (CPU CI) every replica binds the virtual GPU/DLA pair
-to the single device and placement collapses to identity.
+devices its executor uploads frames to, the ``jax.device_put`` placement
+closures it applies per segment where a route crosses chips (identities
+for a replica on one chip), and the weights, copied once at build time
+onto each engine's device; on 1-device hosts (CPU CI) every replica binds
+the virtual GPU/DLA pair to the single device and placement collapses to
+identity.
 
 ``FleetRouter`` assigns work to replicas by load: least outstanding
 frames, deadline-pressure tie-break (a replica already carrying
@@ -225,6 +227,11 @@ class FleetServer:
     so the open-loop traffic driver and the benches run unchanged; every
     constructor knob is applied to each replica. ``pool`` defaults to a
     ``DevicePool.discover()`` over the plan's engines.
+
+    Replica ``r``'s recorder tags its spans with ``replica=r``. The fleet's
+    own span, ``serve.route`` (stream, replica) around the router's
+    decision for each offer, goes to the first replica's recorder
+    (``tracer``), so the replicas' recorders hold each span once.
     """
 
     def __init__(
@@ -268,15 +275,20 @@ class FleetServer:
                 batching=batching,
                 place_fns=pool.place_fns(r, replicas),
                 engine_params=pool.place_params(models, r, replicas),
+                engine_devices=pool.engine_devices(r, replicas),
                 dispatch=dispatch,
                 jit_segments=jit_segments,
                 replanner=replanners[r] if replanners is not None else None,
                 admission=admission,
                 resolution_flexible=resolution_flexible,
+                replica=r,
             )
             for r in range(replicas)
         ]
         self.handles = [LocalReplica(s) for s in self.servers]
+        # the fleet's own spans go to the first replica's recorder, so that
+        # merging the replicas' recorders counts each of them once
+        self.tracer = self.servers[0].tracer
         self.router = FleetRouter(replicas, seed=router_seed)
         self.executor = _FleetExecutorView(self.servers)
         self._t0: float | None = None
@@ -297,11 +309,13 @@ class FleetServer:
         to the least-loaded replica."""
         if self._t0 is None:
             self._t0 = time.perf_counter()
-        if isinstance(target, str):
-            r = self.router.route_arrival(target, self._loads(), self._deadline_of(target))
-        else:
-            r = self.router.pick(self._loads())
-            self.router.routed_frames[r] += 1
+        with self.tracer.span("serve.route", stream=target) as sp:
+            if isinstance(target, str):
+                r = self.router.route_arrival(target, self._loads(), self._deadline_of(target))
+            else:
+                r = self.router.pick(self._loads())
+                self.router.routed_frames[r] += 1
+            sp.note(replica=r)
         return self.handles[r].offer(target, frame)
 
     def tick(self):

@@ -18,7 +18,9 @@ The server owns one ``serve.tracing.SpanRecorder``, ``tracer``, shared
 with its executor and off until ``tracer.enable()``: it records
 ``serve.offer`` (stream, decision, frame), ``serve.tick`` and
 ``serve.fold`` around the executor's own spans, and ``report()`` carries
-its counters and long spans under ``"spans"`` while it is on.
+its counters and long spans under ``"spans"`` while it is on. A fleet's
+replica ``r`` passes ``replica=r``, and its recorder tags every span it
+writes to the profiler's trace with it.
 """
 from __future__ import annotations
 
@@ -62,8 +64,10 @@ class MultiStreamServer:
         resolution_flexible: bool | list[bool] = False,
         batching: BatchConfig | None = None,
         engine_params: list[list] | None = None,
+        engine_devices: list | None = None,
+        replica: int | None = None,
     ):
-        self.tracer = SpanRecorder()
+        self.tracer = SpanRecorder(replica=replica)
         self.executor = StreamExecutor(
             models,
             plan,
@@ -77,6 +81,7 @@ class MultiStreamServer:
             batching=batching,
             engine_params=engine_params,
             tracer=self.tracer,
+            engine_devices=engine_devices,
         )
         self.replanner = replanner
         self.metrics = ServeMetrics(

@@ -14,15 +14,19 @@ attributes, and also:
   in bounded memory;
 * the last ``ring`` spans, in the order they ended;
 * ``python.gc`` spans, tagged with the generation, for each garbage
-  collection the interpreter makes.
+  collection the interpreter makes: one span per collection in the whole
+  process, however many recorders are on, recorded by the recorder whose
+  span is open (the first one switched on where none is).
 
 Each span is also written as a ``jax.profiler.TraceAnnotation`` with its
 attributes, which the profiler records while it runs: the spans then sit
-on the same clock as the device's operations. Spans that serve frames carry
-``frames``, the frames' ``<stream>/<frame id>`` keys joined by ``;``, so
-one frame's spans share its identifier.
+on the same clock as the device's operations. A replica's recorder (a
+fleet's, ``replica=r``) adds ``replica`` to every span it writes, unless
+the span set its own. Spans that serve frames carry ``frames``, the frames'
+``<stream>/<frame id>`` keys joined by ``;``, so one frame's spans share
+its identifier.
 
-The recorder expects one thread: the one that ticks the server.
+The recorders expect one thread: the one that ticks the servers.
 """
 from __future__ import annotations
 
@@ -80,14 +84,17 @@ class Span:
         rec = self.rec
         self.t1 = rec.clock()
         if self.ann is not None:
+            if rec.replica is not None and "replica" not in self.attrs:
+                self.ann.set_metadata(replica=rec.replica)
             self.ann.__exit__(None, None, None)
             self.ann = None
         rec._stack.pop()
         rec._close(self)
 
     def as_dict(self, t0: float = 0.0) -> dict:
+        tag = {} if self.rec.replica is None else {"replica": self.rec.replica}
         return {"id": self.id, "name": self.name, "parent": self.parent, "depth": self.depth,
-                "start_s": self.t0 - t0, "dur_s": self.dur, "self_s": self.self_s, **self.attrs}
+                "start_s": self.t0 - t0, "dur_s": self.dur, "self_s": self.self_s, **tag, **self.attrs}
 
 
 class _Off:
@@ -107,16 +114,31 @@ class _Off:
 
 OFF = _Off()
 
+_ON: list["SpanRecorder"] = []  # recorders on, in the order they were switched on
+_gc_span: Span | None = None  # the collection in progress
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The process's one ``gc.callbacks`` hook while any recorder is on."""
+    global _gc_span
+    if phase == "start":
+        rec = next((r for r in _ON if r._stack), _ON[0])
+        _gc_span = Span(rec, "python.gc", {"generation": info["generation"]})
+        _gc_span.__enter__()
+    elif _gc_span is not None:
+        s, _gc_span = _gc_span, None
+        s.__exit__()
+
 
 class SpanRecorder:
     def __init__(self, long_s: float = LONG_S, long_keep: int = LONG_KEEP, ring: int = RING,
-                 clock=time.perf_counter):
+                 clock=time.perf_counter, replica: int | None = None):
         self.enabled = False
         self.long_s = long_s
         self.clock = clock
+        self.replica = replica
         self._stack: list[Span] = []
         self._ids = itertools.count(1)
-        self._gc: Span | None = None
         self._long: deque[list[Span]] = deque(maxlen=long_keep)
         self._ring: deque[Span] = deque(maxlen=ring)
         self.recorded = 0  # spans ended since the last reset
@@ -129,13 +151,17 @@ class SpanRecorder:
     def enable(self) -> None:
         if not self.enabled:
             self.enabled = True
-            gc.callbacks.append(self._on_gc)
+            if not _ON:
+                gc.callbacks.append(_on_gc)
+            _ON.append(self)
             self.reset()
 
     def disable(self) -> None:
         if self.enabled:
             self.enabled = False
-            gc.callbacks.remove(self._on_gc)
+            _ON.remove(self)
+            if not _ON:
+                gc.callbacks.remove(_on_gc)
 
     def reset(self) -> None:
         """Forget every recorded span and counter (spans still open are
@@ -183,14 +209,6 @@ class SpanRecorder:
                 self.long_dropped += 1
             self._long.append(s.kept)
             s.kept = None
-
-    def _on_gc(self, phase: str, info: dict) -> None:
-        if phase == "start":
-            s = self._gc = Span(self, "python.gc", {"generation": info["generation"]})
-            s.__enter__()
-        elif self._gc is not None:
-            s, self._gc = self._gc, None
-            s.__exit__()
 
     # -- reading --------------------------------------------------------------
 

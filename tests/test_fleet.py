@@ -124,6 +124,104 @@ print("OK")
     assert run_subprocess(code, devices=4).strip().endswith("OK")
 
 
+@pytest.mark.parametrize("donate", [False, True], ids=["donation_off", "donation_on"])
+def test_stage_in_uploads_each_frame_straight_to_its_replicas_device(donate):
+    """On a 4-device host, with segments donating their state or not: the
+    state stage-in hands to the first segment already lives on the device
+    of its route's first engine and on no other, so the frames of replicas
+    1-3 never pass through device 0; a one-chip replica's ``place_fns`` are
+    identities, while ``replicas=2`` still puts across its two devices; the
+    caller's frame survives donation; fleet outputs equal one replica's."""
+    from conftest import run_subprocess
+
+    code = f"""
+import jax, jax.numpy as jnp, numpy as np
+from repro.serve import build_server
+host = np.random.default_rng(0).normal(size=(1, 32, 32, 3)).astype(np.float32)
+dev0 = jnp.asarray(host)  # a frame that already lives on device 0
+outs = {{}}
+for r in (1, 2, 4):
+    b = build_server(img=32, base=8, n_pix=3, n_yolo=1, seed=0, replicas=r)
+    servers = b.server.servers if r > 1 else [b.server]
+    staged = []
+    for k, srv in enumerate(servers):
+        ex = srv.executor
+        ex._donate = {donate}
+        if r == 1:
+            assert ex.engine_devices is None
+            continue
+        devs = b.server.pool.replica_devices(k, r)
+        assert ex.engine_devices == [devs[0], devs[-1]]
+        tree = {{"x": jnp.ones((2, 2))}}
+        if r == 4:
+            assert all(fn(tree) is tree for fn in ex.place_fns)
+        else:
+            placed = [{{d for x in jax.tree.leaves(fn(tree)) for d in x.devices()}} for fn in ex.place_fns]
+            assert placed == [{{devs[0]}}, {{devs[1]}}] and devs[0] != devs[1], placed
+
+        def stage_in(*a, ex=ex, orig=ex._stage_in, k=k):
+            flights = orig(*a)
+            for f in flights:
+                first = ex.engine_devices[f.route[0].engine]
+                staged.append((k, first, {{d for x in jax.tree.leaves(f.state) for d in x.devices()}}))
+            return flights
+
+        ex._stage_in = stage_in
+    for s in b.streams:
+        assert b.server.offer(s.name, host) == "admit"
+        assert b.server.offer(s.name, dev0) == "admit"
+    outs[r] = b.server.drain()
+    assert np.array_equal(np.asarray(dev0), host)  # never donated away
+    if r > 1:
+        assert len(staged) == 2 * len(b.streams)
+        for k, first, on in staged:
+            assert on == {{first}}, (k, first, on)
+            assert first in b.server.pool.replica_devices(k, r)
+            if r == 4 and k > 0:
+                assert jax.devices()[0] not in on
+    for name, got in outs[r].items():
+        assert len(got) == 2
+        for g, w in zip(got, outs[1][name], strict=True):
+            for gl, wl in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+                np.testing.assert_allclose(np.asarray(gl), np.asarray(wl), atol=1e-5)
+print("OK")
+"""
+    assert run_subprocess(code, devices=4).strip().endswith("OK")
+
+
+def test_one_device_stage_in_and_placement_stay_as_they_were(staged_pair, engines):
+    """On a 1-device host a fleet's replicas have no engine devices, their
+    ``place_fns`` are identities, and stage-in hands the first segment the
+    model's initial state as ``init_state`` made it (a host frame stays on
+    the host until the segment takes it)."""
+    gpu, dla = engines
+    sm_pix, sm_yolo = staged_pair
+    pool = DevicePool((dla, gpu), devices=jax.devices()[:1])
+    assert pool.engine_devices(0, 2) is None
+    plan = core.plan([sm_pix.graph, sm_yolo.graph], [dla, gpu])
+    streams = [StreamSpec("mri-0", 0), StreamSpec("det-0", 1)]
+    fleet = FleetServer([sm_pix, sm_yolo], plan, streams, replicas=2, pool=pool, max_queue=8)
+    staged = []
+    tree = {"x": jax.numpy.ones((2, 2))}
+    for srv in fleet.servers:
+        ex = srv.executor
+        assert ex.engine_devices is None and all(fn(tree) is tree for fn in ex.place_fns)
+        if ex._donate:
+            pytest.skip("a backend whose segments donate copies the frame on upload")
+
+        def stage_in(*a, orig=ex._stage_in):
+            flights = orig(*a)
+            staged.extend(f.state["x"] for f in flights)
+            return flights
+
+        ex._stage_in = stage_in
+    frame = np.random.default_rng(0).normal(size=(1, 32, 32, 3)).astype(np.float32)
+    for s in streams:
+        fleet.offer(s.name, frame)
+    fleet.drain()
+    assert len(staged) == 2 and all(isinstance(x, np.ndarray) for x in staged)
+
+
 # ---- FleetRouter -----------------------------------------------------------
 
 

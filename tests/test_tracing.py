@@ -21,6 +21,7 @@ from repro.core.pipeline import StagedModel, executable_label
 from repro.core.plan_ir import make_plan_ir
 from repro.models import Pix2PixConfig, Pix2PixGenerator
 from repro.serve import BatchConfig, MultiStreamServer, SLOPolicy, StreamSpec
+from repro.serve import tracing
 from repro.serve.tracing import OFF, SpanRecorder
 
 
@@ -130,7 +131,7 @@ def test_garbage_collections_are_spans_with_their_generation():
             gc.collect()
     finally:
         rec.disable()
-    assert rec._on_gc not in gc.callbacks
+    assert tracing._on_gc not in gc.callbacks
     gcs = [s for s in rec.recent() if s.name == "python.gc"]
     assert gcs and gcs[-1].attrs == {"generation": 2} and gcs[-1].parent == tick.id
     assert tick.child_s >= gcs[-1].dur
@@ -219,6 +220,121 @@ def test_reset_metrics_starts_the_recorders_window_afresh():
         assert srv.tracer.counters == {} and srv.tracer.recent() == []
     finally:
         srv.tracer.disable()
+
+
+# ---- a fleet's recorders ------------------------------------------------------------
+
+
+def _merge_counters(recorders):
+    """The benchmark's merge of the replicas' counters (``bench.harness``)."""
+    import pathlib
+    import sys
+
+    root = str(pathlib.Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import harness
+
+    return harness.merge_counters([r.summary()["counters"] for r in recorders])
+
+
+def _toy_fleet(replicas=4):
+    from repro.serve import FleetServer
+
+    plan = make_plan_ir(("toy",), ("E0", "E1"), [[(0, 0, 2), (1, 2, 4)]])
+    streams = [StreamSpec(f"s{i}", 0) for i in range(2 * replicas)]
+    return FleetServer([_toy_model()], plan, streams, replicas=replicas, max_queue=8,
+                       jit_segments=False)
+
+
+def test_one_gc_span_per_collection_however_many_recorders_are_on():
+    fleet = _toy_fleet()
+    tracers = [s.tracer for s in fleet.servers]
+    assert [t.replica for t in tracers] == [0, 1, 2, 3]
+    was = gc.isenabled()
+    gc.disable()  # no collection but the forced one
+    for t in tracers:
+        t.enable()
+    try:
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        with tracers[2].span("serve.tick") as tick:
+            gc.collect()
+    finally:
+        for t in tracers:
+            t.disable()
+        if was:
+            gc.enable()
+    assert tracing._on_gc not in gc.callbacks
+    assert _merge_counters(tracers)["python.gc"]["count"] == 1
+    # the collection is recorded inside the span that was open, by its recorder
+    (s,) = [s for s in tracers[2].recent() if s.name == "python.gc"]
+    assert s.parent == tick.id and s.as_dict()["replica"] == 2
+
+
+def test_route_spans_count_the_offers_made_while_on():
+    fleet = _toy_fleet()
+    tracers = [s.tracer for s in fleet.servers]
+    for name in ("s0", "s1"):  # offered while off: not counted
+        fleet.offer(name, jnp.ones((1, 8)))
+    for t in tracers:
+        t.enable()
+    try:
+        for k in range(3):
+            for i in range(8):
+                fleet.offer(f"s{i}", jnp.full((1, 8), float(k)))
+            fleet.tick()
+        _drain(fleet)
+    finally:
+        for t in tracers:
+            t.disable()
+    counters = _merge_counters(tracers)
+    assert counters["serve.route"]["count"] == 24 and counters["serve.offer"]["count"] == 24
+    # the fleet's spans go to the first replica's recorder alone
+    assert all("serve.route" not in t.counters for t in tracers[1:])
+    routes = [s for s in tracers[0].recent() if s.name == "serve.route"]
+    assert len(routes) == 24
+    for s in routes:
+        assert s.attrs["replica"] == fleet.router.replica_of(s.attrs["stream"])
+        assert s.as_dict()["replica"] == s.attrs["replica"]
+
+
+def test_every_replica_span_carries_its_replica(tmp_path):
+    import pathlib
+    import sys
+
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from bench import spans as bench_spans
+
+    fleet = _toy_fleet()
+    tracers = [s.tracer for s in fleet.servers]
+    for t in tracers:
+        t.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for k in range(2):
+            for i in range(8):
+                fleet.offer(f"s{i}", jnp.full((1, 8), float(k)))
+            fleet.tick()
+        _drain(fleet)
+    finally:
+        jax.profiler.stop_trace()
+        for t in tracers:
+            t.disable()
+    routed = {s: fleet.router.replica_of(s) for s in (f"s{i}" for i in range(8))}
+    assert set(routed.values()) == {0, 1, 2, 3}
+    for r, t in enumerate(tracers):
+        mine = [s for s in t.recent() if s.name != "serve.route"]
+        assert {s.name for s in mine} >= {"serve.offer", "serve.tick", "executor.dispatch"}
+        assert all(s.as_dict()["replica"] == r for s in mine)
+        assert all(routed[s.attrs["stream"]] == r for s in mine if s.name == "serve.offer")
+    # in the profiler's trace too, each span names the replica whose work it was
+    traced = [sp for sp in bench_spans.load(str(tmp_path))["spans"] if sp[0].startswith("serve.")]
+    assert {sp[0] for sp in traced} >= {"serve.route", "serve.offer", "serve.tick"}
+    for name, _, _, attrs in traced:
+        assert "replica" in attrs, (name, attrs)
+        if name in ("serve.route", "serve.offer"):
+            assert int(attrs["replica"]) == routed[attrs["stream"]]
+    assert {int(a["replica"]) for n, _, _, a in traced if n == "serve.tick"} == {0, 1, 2, 3}
 
 
 # ---- queue wait -------------------------------------------------------------------
